@@ -50,6 +50,10 @@ def _run_shard_loss() -> dict:
                      if not f.startswith("--xla_force_host_platform_device_count"))
     env["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count=2".strip())
+    # a CPU rehearsal by design: the child reads modeled virtual time on
+    # forced host devices, and must never reach for a chip this process
+    # (or a parent benchmark) may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
@@ -137,6 +141,9 @@ def run() -> None:
     ]
     table(rows, f"chaos recovery gates (reseat <= {RESEAT_BOUND} ticks, "
                 f"recovery <= {RECOVERY_BOUND} ticks, zero retraces)")
+    print("CPU rehearsal: the shard-loss child runs on forced host devices "
+          "and every latency is modeled virtual time; these numbers are "
+          "not chip measurements")
 
     tick_us = shard["report"]["clock_s"] / shard["report"]["n_ticks"] * 1e6
     csv_line("chaos_shard_loss", tick_us,

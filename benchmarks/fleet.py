@@ -46,6 +46,10 @@ def _run_point(k: int) -> dict:
                      if not f.startswith("--xla_force_host_platform_device_count"))
     env["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={k}".strip())
+    # a CPU rehearsal by design: the child reads modeled virtual time on
+    # forced host devices, and must never reach for a chip this process
+    # (or a parent benchmark) may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
@@ -87,6 +91,8 @@ def run() -> None:
         })
     table(rows, "fleet scaling (virtual-time frames/s, weak scaling "
                f"at {STREAMS_PER_SHARD} streams/shard)")
+    print("CPU rehearsal: forced host devices and modeled virtual time; "
+          "these numbers are not chip measurements")
 
     base = reports[1]["frames_per_vs"]
     for k in DEVICE_COUNTS:

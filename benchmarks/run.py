@@ -57,9 +57,12 @@ def _parse_argv(argv: list[str]) -> list[str]:
 def main() -> int:
     import importlib
 
+    from repro.launch.compile_cache import configure_compile_cache
+
     from .common import drain_results
 
     only = _parse_argv(sys.argv[1:]) or MODULES
+    configure_compile_cache()
     failures = []
     report: dict[str, dict] = {}
     for name in only:

@@ -119,7 +119,7 @@ _SCATTER = {"scatter", "scatter-add", "scatter_add", "scatter-mul",
 _HOST = {"device_put", "infeed", "outfeed", "copy_to_host_async"}
 
 # primitives that are pure bookkeeping at trace level
-_FREE = {"pjit", "custom_jvp_call", "custom_vjp_call", "closed_call",
+_FREE = {"jit", "custom_jvp_call", "custom_vjp_call", "closed_call",
          "core_call", "named_call", "remat", "checkpoint", "custom_vmap_call",
          "program_id", "num_programs"}
 
@@ -223,7 +223,7 @@ def count_jaxpr(jaxpr, _path: str = "") -> Counts:
 
     Deterministic: equations are visited in program order and every
     contribution is exact arithmetic over static avals.  Nested program
-    structure (``pjit`` of ``pjit``, scans, conds, Pallas kernels) is
+    structure (``jit`` of ``jit``, scans, conds, Pallas kernels) is
     recursed into, so counts are invariant to jit-of-jit nesting — the
     property pinned by ``tests/test_cert_properties.py``.
     """
@@ -293,11 +293,11 @@ def program_io_bytes(jaxpr) -> tuple[float, float]:
 
 def outer_donated_invars(jaxpr) -> Optional[tuple[bool, ...]]:
     """Donation mask of a traced jitted call: ``make_jaxpr`` of a jitted
-    function yields one outer ``pjit`` equation whose ``donated_invars``
+    function yields one outer ``jit`` equation whose ``donated_invars``
     records which (flattened) inputs the compiled program may alias.
     ``None`` when the program is not a single jitted call."""
     inner = _inner_jaxpr(jaxpr)
-    if len(inner.eqns) == 1 and inner.eqns[0].primitive.name == "pjit":
+    if len(inner.eqns) == 1 and inner.eqns[0].primitive.name == "jit":
         mask = inner.eqns[0].params.get("donated_invars")
         return tuple(bool(b) for b in mask) if mask is not None else None
     return None

@@ -59,9 +59,18 @@ from repro.perception.pipelines import (
 
 from .executor import PipelinedExecutor
 
-__all__ = ["BatchedStreamState", "BatchedPerceptionEngine"]
+__all__ = ["BatchedStreamState", "BatchedPerceptionEngine",
+           "fused_batch_program"]
 
 _NO_PAYLOAD = object()
+
+
+def fused_batch_program(built: BuiltPipeline):
+    """A rung's fused batch program: ``vmap`` over the padded slot batch
+    of ``preprocess_device`` + ``infer`` — what the executor jits as its
+    ``step``."""
+    return jax.vmap(
+        lambda raw: built.infer(preprocess_device(raw, built.scale, built.pad)))
 
 
 @dataclasses.dataclass
@@ -144,10 +153,7 @@ class BatchedPerceptionEngine:
         self.obs = obs
         self.obs_tag = obs_tag
 
-        built = self.built
-        step_fn = jax.vmap(
-            lambda raw: built.infer(preprocess_device(raw, built.scale, built.pad))
-        )
+        step_fn = fused_batch_program(self.built)
         # fleet sharding: the executor carries the slot batch (and every
         # program output) as a NamedSharding over the mesh's data axis;
         # the engine seats streams into per-shard slot blocks so a

@@ -19,47 +19,66 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["mamba2_ssd_fwd"]
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, h_scr, *, nc: int):
+def _lower_ones(t: int) -> jax.Array:
+    """(T, T) inclusive lower-triangular ones: ``_lower_ones(t) @ x`` is
+    the prefix sum of ``x`` over rows.  Mosaic has no ``cumsum``
+    lowering; the triangular matmul runs on the MXU instead."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    return (row >= col).astype(jnp.float32)
+
+
+def _dot(a, b, contract=((1,), (0,)), precision=None):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=precision)
+
+
+def _kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, o_ref, h_scr):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, 0].astype(jnp.float32)        # (T, HB, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)      # (T, HB)
-    a = a_ref[0].astype(jnp.float32)           # (HB,)
     bm = b_ref[0].astype(jnp.float32)          # (T, N)
     cm = c_ref[0].astype(jnp.float32)          # (T, N)
-    t, hb, p = x.shape
-    n = bm.shape[-1]
+    hb, t, p = x_ref.shape[1:]
+    lower = _lower_ones(t)
+    hi = jax.lax.Precision.HIGHEST
 
-    da = dt * a[None, :]                       # (T, HB) ≤ 0
-    cum = jnp.cumsum(da, axis=0)               # inclusive
-    total = cum[-1]                            # (HB,)
+    # shared by every head in the block (one B/C group): C_t · B_u
+    scores = _dot(cm, bm, ((1,), (1,)))                              # (T, T)
+    keep = lower > 0                                                 # u ≤ t
 
-    # intra-chunk: gated[t,u,h] = (C_t·B_u) exp(cum[t]-cum[u]) dt_u, u ≤ t
-    scores = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)  # (T, T)
-    pair = cum[:, None, :] - cum[None, :, :]                        # (T,T,HB) ≤0 kept
-    tri = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= jax.lax.broadcasted_iota(
-        jnp.int32, (t, t), 1
-    )
-    wmat = jnp.where(tri[:, :, None], jnp.exp(pair), 0.0)
-    gated = scores[:, :, None] * wmat * dt[None, :, :]              # (T,T,HB)
-    y_intra = jnp.einsum("tuh,uhp->thp", gated, x)
+    # heads are a static loop so every value is a 2-D tile: Mosaic cannot
+    # lay out the (T, T, HB) pairwise-decay tensor
+    for h in range(hb):
+        x = x_ref[0, h].astype(jnp.float32)        # (T, P)
+        a = a_ref[0, h].astype(jnp.float32)        # (1, 1)
+        dt_col = dtc_ref[0, h].astype(jnp.float32)     # (T, 1)
+        dt_row = dtr_ref[0, h, 0].astype(jnp.float32)  # (1, T)
+        da = dt_col * a                                              # (T, 1) ≤ 0
+        cum_col = _dot(lower, da, precision=hi)                      # (T, 1)
+        cum_row = _dot(dt_row * a, lower, ((1,), (1,)), precision=hi)  # (1, T)
+        # the chunk's total decay, replicated down a column (Mosaic cannot
+        # broadcast a (1, 1) value over both tile axes)
+        total_t = _dot(jnp.ones((t, t), jnp.float32), da, precision=hi)   # (T, 1)
+        total_p = _dot(jnp.ones((p, t), jnp.float32), da, precision=hi)   # (P, 1)
 
-    # inter-chunk from carried state: y[t] += C_t · (exp(cum[t]) ⊙ h_prev)
-    h_prev = h_scr[...].reshape(hb, p, n)
-    y_inter = jnp.einsum("tn,th,hpn->thp", cm, jnp.exp(cum), h_prev)
+        # intra-chunk: gated[t,u] = (C_t·B_u) exp(cum[t]-cum[u]) dt_u, u ≤ t
+        decay = jnp.exp(jnp.minimum(cum_col - cum_row, 0.0))
+        gated = jnp.where(keep, scores * decay, 0.0) * dt_row
+        y = _dot(gated, x)
 
-    # state update: h = exp(total) h_prev + sum_u exp(total-cum[u]) dt_u B_u x_u
-    decay_to_end = jnp.exp(total[None, :] - cum) * dt               # (T, HB)
-    h_new = jnp.exp(total)[:, None, None] * h_prev + jnp.einsum(
-        "th,tn,thp->hpn", decay_to_end, bm, x
-    )
-    h_scr[...] = h_new.reshape(hb, p * n)
+        # inter-chunk from carried state: y[t] += exp(cum[t]) C_t · h_prev
+        h_prev = h_scr[h]                                            # (P, N)
+        y = y + jnp.exp(cum_col) * _dot(cm, h_prev, ((1,), (1,)))
 
-    o_ref[0, 0] = (y_intra + y_inter).astype(o_ref.dtype)
+        # state: h = exp(total) h_prev + sum_u exp(total-cum[u]) dt_u x_u B_u^T
+        to_end = jnp.exp(total_t - cum_col) * dt_col                 # (T, 1)
+        h_scr[h] = jnp.exp(total_p) * h_prev + _dot(x * to_end, bm, ((0,), (0,)))
+        o_ref[0, h] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "head_block", "interpret"))
@@ -80,27 +99,31 @@ def mamba2_ssd_fwd(
     nc = s // chunk
     nh = h // head_block
 
-    xt = x.transpose(0, 2, 1, 3).reshape(b, nh, head_block, s, p)
-    xt = xt.transpose(0, 1, 3, 2, 4)          # (B, NH, S, HB, P)
-    dtt = dt.transpose(0, 2, 1).reshape(b, nh, head_block, s).transpose(0, 1, 3, 2)
-    at = a.reshape(nh, head_block)
+    # head-major layouts whose trailing block dims are either whole array
+    # dims or 8-row multiples, as Mosaic requires: dt arrives both as
+    # per-head columns (B, H, S, 1) and as per-chunk rows (B, H, NC, 1, T)
+    xt = x.transpose(0, 2, 1, 3)                     # (B, H, S, P)
+    dtt = dt.transpose(0, 2, 1)                      # (B, H, S)
+    dt_col = dtt[..., None]
+    dt_row = dtt.reshape(b, h, nc, 1, chunk)
+    a4 = a.reshape(nh, head_block, 1, 1)
 
-    kernel = functools.partial(_kernel, nc=nc)
     out = pl.pallas_call(
-        kernel,
+        _kernel,
         grid=(b, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, head_block, p), lambda b_, h_, j: (b_, h_, j, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, head_block), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, head_block), lambda b_, h_, j: (h_, 0)),
+            pl.BlockSpec((1, head_block, chunk, p), lambda b_, h_, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, head_block, chunk, 1), lambda b_, h_, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, head_block, 1, 1, chunk),
+                         lambda b_, h_, j: (b_, h_, j, 0, 0)),
+            pl.BlockSpec((1, head_block, 1, 1), lambda b_, h_, j: (h_, 0, 0, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, j: (b_, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, j: (b_, j, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, chunk, head_block, p), lambda b_, h_, j: (b_, h_, j, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, nh, s, head_block, p), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((head_block, p * n), jnp.float32)],
+        out_specs=pl.BlockSpec((1, head_block, chunk, p),
+                               lambda b_, h_, j: (b_, h_, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, p), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((head_block, p, n), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, at, bmat, cmat)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, s, h, p)
+    )(xt, dt_col, dt_row, a4, bmat, cmat)
+    return out.transpose(0, 2, 1, 3)

@@ -1,13 +1,13 @@
 """jit'd public wrappers for the Pallas kernels (the ``ops.py`` layer).
 
-On TPU these dispatch the compiled kernels; on CPU (this container) they
-run in interpret mode, or fall back to the pure-jnp reference when
-``REPRO_KERNEL_BACKEND=ref``.  Model code selects the backend via
-``cfg.attn_impl`` ("xla" | "pallas").
+On TPU these dispatch the compiled kernels; on the CPU backend (tests)
+they run in interpret mode.  Any other backend is refused: these kernels
+are written for the TPU, and a silent interpreter on an accelerator
+would hide that the compiled path never ran.  Model code selects the
+backend via ``cfg.attn_impl`` ("xla" | "pallas").
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -17,24 +17,24 @@ from .flash_attention import flash_attention_fwd
 from .decode_attention import decode_attention_fwd
 from .rwkv6_scan import rwkv6_wkv_fwd
 from .mamba2_ssd import mamba2_ssd_fwd
-from . import ref as _ref
 
 __all__ = ["flash_attention", "decode_attention", "rwkv6_wkv", "mamba2_ssd", "default_interpret"]
 
 
 def default_interpret() -> bool:
-    """Interpret mode unless running on a real TPU backend."""
-    return jax.default_backend() != "tpu"
-
-
-def _use_ref() -> bool:
-    return os.environ.get("REPRO_KERNEL_BACKEND", "") == "ref"
+    """Compiled on TPU, interpreted on CPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"default backend is {backend!r}")
 
 
 def flash_attention(q, k, v, causal=True, window: Optional[int] = None,
                     block_q: int = 128, block_kv: int = 128):
-    if _use_ref():
-        return _ref.flash_attention_ref(q, k, v, causal, window)
     return flash_attention_fwd(
         q, k, v, causal=causal, window=window,
         block_q=block_q, block_kv=block_kv, interpret=default_interpret(),
@@ -43,8 +43,6 @@ def flash_attention(q, k, v, causal=True, window: Optional[int] = None,
 
 def decode_attention(q, k_cache, v_cache, positions, next_pos,
                      window: Optional[int] = None, block_kv: int = 128):
-    if _use_ref():
-        return _ref.decode_attention_ref(q, k_cache, v_cache, positions, next_pos, window)
     return decode_attention_fwd(
         q, k_cache, v_cache, positions, next_pos,
         window=window, block_kv=block_kv, interpret=default_interpret(),
@@ -52,14 +50,10 @@ def decode_attention(q, k_cache, v_cache, positions, next_pos,
 
 
 def rwkv6_wkv(r, k, v, logw, u, chunk: int = 64):
-    if _use_ref():
-        return _ref.rwkv6_wkv_ref(r, k, v, logw, u)
     return rwkv6_wkv_fwd(r, k, v, logw, u, chunk=chunk, interpret=default_interpret())
 
 
 def mamba2_ssd(x, dt, a, bmat, cmat, chunk: int = 64, head_block: int = 8):
-    if _use_ref():
-        return _ref.mamba2_ssd_ref(x, dt, a, bmat, cmat)
     return mamba2_ssd_fwd(
         x, dt, a, bmat, cmat, chunk=chunk, head_block=head_block,
         interpret=default_interpret(),

@@ -3,14 +3,15 @@
 Grid ``(batch, head, chunk)`` with the chunk axis innermost: the (dk × dv)
 recurrent state lives in VMEM scratch and carries across chunks (TPU grids
 execute the trailing axis sequentially).  Per chunk the kernel computes the
-intra-chunk pairwise-decay attention term on the MXU plus the inter-chunk
-state read, then folds the chunk into the state — the same math as
+intra-chunk pairwise-decay attention term on the VPU plus the inter-chunk
+state read on the MXU, then folds the chunk into the state — the same math as
 ``repro.models.rwkv6._wkv_chunked``, validated against the step-by-step
 oracle ``rwkv6_recurrent``.
 
 TPU adaptation notes: the (T × T × dk) pairwise-decay tensor of the jnp
-path is never materialized — the kernel loops the decay factorization
-through f32 VMEM tiles of (T, dk), and state updates run on the VPU.
+path is never materialized — the kernel loops over source positions
+through f32 VMEM tiles of (T, dk), and chunk-local prefix sums are
+triangular-ones matmuls (Mosaic has no ``cumsum``).
 """
 from __future__ import annotations
 
@@ -34,47 +35,61 @@ __all__ = ["rwkv6_wkv_fwd"]
 _STATE_TILE = 32
 
 
+def _prefix_ones(t: int, strict: bool = False) -> jax.Array:
+    """(T, T) lower-triangular ones: ``_prefix_ones(t) @ x`` is the
+    inclusive prefix sum of ``x`` over rows (``strict``: exclusive).
+    Mosaic has no ``cumsum`` lowering; a triangular matmul runs on the
+    MXU instead, and at HIGHEST precision it keeps f32 accuracy."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    return ((row > col) if strict else (row >= col)).astype(jnp.float32)
+
+
+def _tri_sum(a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def _fold_tile(r, k, v, lw, u, s):
-    """One ≤32-wide tile: (y, s_new) for f32 (T, K) inputs and (K, K) state.
+    """One ≤32-wide tile: (y, s_new) for f32 (T, K) inputs, (1, K) bonus
+    ``u`` and (K, K) state.
 
     The state-fold decay ``exp(sum_{j>u} lw_j)`` is computed from a direct
-    suffix cumsum, not ``total - cum[u]`` — the latter cancels two large
+    suffix sum, not ``total - cum[u]`` — the latter cancels two large
     prefix sums and loses the low bits of exactly the exponents that matter
     (late positions, where the factor is near 1).
     """
     t = r.shape[0]
-    cum = jnp.cumsum(lw, axis=0)                       # inclusive prefix
+    lower = _prefix_ones(t)
+    cum = _tri_sum(lower, lw)                          # inclusive prefix
     cum_tm1 = cum - lw                                 # exclusive prefix
 
     # intra-tile: y[t] = sum_{u<t} (r_t·exp(cum_tm1[t]-cum[u])·k_u) v_u
     #           + (r_t·diag(u)·k_t) v_t
     # pairwise log-domain form: exponents are ≤ 0 for every kept (t, u)
-    # pair, so no overflow for arbitrarily strong decay.  The (T, T, K)
-    # tile is ≤ 0.25 MiB VMEM at T=32, K=64 (bounded, static).
-    pair = cum_tm1[:, None, :] - cum[None, :, :]       # (T, T, K)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) > jax.lax.broadcasted_iota(
-        jnp.int32, (t, t), 1
-    )
-    wpair = jnp.where(tri[:, :, None], jnp.exp(pair), 0.0)
-    amat = jnp.einsum(
-        "tk,uk,tuk->tu", r, k, wpair,
-    )
-    diag = jnp.sum(r * u[None, :] * k, axis=1)         # (T,)
-    y = jnp.dot(amat, v, preferred_element_type=jnp.float32)
-    y = y + diag[:, None] * v
+    # pair, so no overflow for arbitrarily strong decay.  The loop runs
+    # over source positions u (static, ≤ 32) so every value stays a 2-D
+    # (T, K) tile: Mosaic cannot lay out the (T, T, K) pairwise tensor.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+    y = jnp.sum(r * u * k, axis=1, keepdims=True) * v
+    for j in range(t - 1):
+        decay = jnp.exp(jnp.minimum(cum_tm1 - cum[j:j + 1], 0.0))   # (T, K)
+        a_col = jnp.sum(r * k[j:j + 1] * decay, axis=1, keepdims=True)
+        y = y + jnp.where(rows > j, a_col, 0.0) * v[j:j + 1]
 
     # inter-tile: y[t] += (r_t * exp(cum_tm1[t])) @ S
     y = y + jnp.dot(r * jnp.exp(cum_tm1), s,
                     preferred_element_type=jnp.float32)
 
-    # suffix[u] = sum_{j>u} lw[j], computed without large-sum cancellation
-    scum = jnp.flip(jnp.cumsum(jnp.flip(lw, 0), axis=0), 0)   # inclusive suffix
-    suffix = jnp.concatenate([scum[1:], jnp.zeros_like(scum[:1])], axis=0)
-    total = scum[0]
+    # suffix[u] = sum_{j>u} lw[j]: strict upper-triangular sum, no
+    # large-sum cancellation
+    suffix = _tri_sum(_prefix_ones(t, strict=True).T, lw)
+    # per-key total decay as a (K, 1) column, to scale the state's rows
+    total = _tri_sum(lw.T, jnp.ones((t, 1), jnp.float32))
     kw = k * jnp.exp(suffix)                           # (T, K), exponents ≤ 0
 
     # state update: S = diag(exp(total)) S + sum_u (k_u exp(suffix[u])) v_u^T
-    s_new = s * jnp.exp(total)[:, None] + jnp.dot(
+    s_new = s * jnp.exp(total) + jnp.dot(
         kw.T, v, preferred_element_type=jnp.float32
     )
     return y, s_new
@@ -91,7 +106,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *, ts: int):
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)    # (T, K) log decay ≤ 0
-    u = u_ref[0].astype(jnp.float32)         # (K,)
+    u = u_ref[0].astype(jnp.float32)         # (1, K)
 
     t = r.shape[0]
     s = s_scr[...]
@@ -139,11 +154,13 @@ def rwkv6_wkv_fwd(
             pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, dk), lambda b_, h_, j: (h_, 0)),
+            # (1, 1, K) blocks of the (H, 1, K) bonus: the trailing two
+            # block dims equal the array's, as Mosaic requires
+            pl.BlockSpec((1, 1, dk), lambda b_, h_, j: (h_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, dk), lambda b_, h_, j: (b_, h_, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, dk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
         interpret=interpret,
-    )(prep(r), prep(k), prep(v), prep(logw), u)
+    )(prep(r), prep(k), prep(v), prep(logw), u.reshape(h, 1, dk))
     return out.transpose(0, 2, 1, 3)

@@ -6,6 +6,7 @@ jax device state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh_spec",
            "PROD_SHAPE", "MULTIPOD_SHAPE"]
@@ -20,11 +21,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     with ``data`` for batch sharding; see DESIGN.md §5."""
     shape = MULTIPOD_SHAPE if multi_pod else PROD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_local_mesh(data: int = 1, model: int = 1):
-    """A mesh over whatever devices exist locally (tests / examples).
+def make_local_mesh(data: int = 1, model: int = 1, devices=None):
+    """A mesh over whatever devices exist locally (tests / examples), or
+    over the first ``data * model`` of ``devices`` when given.
 
     An oversubscribed request is factored down to the largest feasible
     shape that preserves *both* axes: ``model`` is the rigid axis (it
@@ -34,10 +36,16 @@ def make_local_mesh(data: int = 1, model: int = 1):
     ``(2, 4)`` — never ``(8, 1)``.  When ``model`` alone exceeds the
     device count it cannot be honored at any data width; that is an
     error, not a silent collapse.
+
+    Every axis is ``Auto``: JAX now defaults to ``Explicit`` axes, under
+    which a reshape that splits a sharded dim (the detector's pooling)
+    raises, while the executor's ``out_shardings`` design leaves
+    partitioning to GSPMD.
     """
     if data < 1 or model < 1:
         raise ValueError(f"mesh axes must be >= 1, got data={data} model={model}")
-    n = len(jax.devices())
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     if model > n:
         raise ValueError(
             f"mesh model={model} cannot be honored: only {n} device(s) "
@@ -45,7 +53,9 @@ def make_local_mesh(data: int = 1, model: int = 1):
             f"--xla_force_host_platform_device_count for CPU experiments)")
     if data * model > n:
         data = max(1, n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=devices[:data * model])
 
 
 def parse_mesh_spec(spec: str) -> dict[str, int]:

@@ -41,6 +41,7 @@ import numpy as np
 from repro.bus import Broker, CopyTransport, SimClock
 from repro.configs import ARCHS, get_config
 from repro.core.deadline import KalmanDeadline, MeanDeadline, PercentileDeadline, WorstObserved
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import Model
 from repro.runtime import (
     AdmissionController,
@@ -348,6 +349,7 @@ def main() -> None:
         ap.error("--trace-out/--obs-period have no effect without --obs")
     if args.obs and args.streams <= 0:
         ap.error("--obs needs multi-tenant mode (--streams N) or --fleet")
+    configure_compile_cache()
 
     if args.fleet:
         if args.streams <= 0:

@@ -4,7 +4,7 @@ Two invariants the certificate's determinism rests on:
 
 * **jit-of-jit nesting invariance** — wrapping a program in any depth of
   ``jax.jit`` must not change its counted cost (the walker recurses
-  through ``pjit`` transparently), so refactoring jit boundaries never
+  through ``jit`` transparently), so refactoring jit boundaries never
   shows up as count drift;
 * **enumeration-order invariance** — merging per-program counts in any
   order yields the same totals, so the certificate does not depend on

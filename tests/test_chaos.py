@@ -346,6 +346,7 @@ def test_shard_loss_rush_hour_two_device_failover(tmp_path):
              if not f.startswith("--xla_force_host_platform_device_count")]
     env["XLA_FLAGS"] = " ".join(
         flags + ["--xla_force_host_platform_device_count=2"])
+    env["JAX_PLATFORMS"] = "cpu"      # a forced-host-device rehearsal
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     proc = subprocess.run(

@@ -313,6 +313,7 @@ def test_fleet_serve_on_two_forced_devices(tmp_path):
              if not f.startswith("--xla_force_host_platform_device_count")]
     env["XLA_FLAGS"] = " ".join(
         flags + ["--xla_force_host_platform_device_count=2"])
+    env["JAX_PLATFORMS"] = "cpu"      # a forced-host-device rehearsal
     env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     proc = subprocess.run(
