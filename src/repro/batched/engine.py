@@ -49,6 +49,7 @@ from jax.sharding import Mesh
 from repro.bus.clock import SimClock
 from repro.core.timing import (STAGE_AXES, StageRecord, StageTimer,
                                TimelineRecorder)
+from repro.obs.span import NO_SPAN
 from repro.perception.data import H, W
 from repro.perception.pipelines import (
     BuiltPipeline,
@@ -148,7 +149,10 @@ class BatchedPerceptionEngine:
         # observability: an ``repro.obs.Observatory`` (duck-typed; pure
         # observation — attaching one never changes control flow or, under
         # a SimClock, any emitted timestamp, so golden replays stay
-        # byte-identical with tracing on).  Mutable so schedulers can
+        # byte-identical with tracing on).  Wall-clock ticks record live
+        # spans (read / inference / post_processing here, upload /
+        # dispatch / readback in the executor); modeled ticks lay their
+        # stage costs on the virtual timeline.  Mutable so schedulers can
         # attach/detach between episodes.
         self.obs = obs
         self.obs_tag = obs_tag
@@ -415,17 +419,37 @@ class BatchedPerceptionEngine:
         for _, slot in snapshot:
             active_mask[slot] = True
 
+        live = self._live()
         if self.depth == 1:
             out = self._tick_sync(frames, snapshot, active_mask,
-                                  payload if has_payload else None)
+                                  payload if has_payload else None, live)
         else:
             out = self._tick_pipelined(frames, snapshot, active_mask,
-                                       payload if has_payload else None)
+                                       payload if has_payload else None,
+                                       live)
         return out if has_payload else out[:2]
 
+    def _live(self):
+        """The observatory that takes live spans, handed to the executor
+        too: the attached one on wall-clock ticks, None under modeled
+        stage costs (nothing ran, so nothing is live; ``_account`` lays
+        the modeled stages on the timeline instead)."""
+        live = self.obs if self.stage_cost is None else None
+        self._exec.obs = live
+        return live
+
+    def _span_tags(self, n_frames: Optional[int]) -> dict:
+        """Tags of this engine's live spans: stream and rung are the
+        ``obs_tag`` (a scheduler sets its ladder's rung name there) or the
+        pipeline's name; the tick id is inherited from the enclosing
+        (scheduler) span."""
+        label = self.obs_tag or self.built.name
+        return {"stream": label, "rung": label, "batch_size": n_frames}
+
     # ---------------- sync (depth-1) path ----------------
-    def _tick_sync(self, frames, snapshot, active_mask, payload):
-        timer = StageTimer()
+    def _tick_sync(self, frames, snapshot, active_mask, payload, live):
+        timer = (StageTimer() if live is None else
+                 StageTimer(tracer=live, tags=self._span_tags(len(snapshot))))
         with timer.stage("read"):
             slot_frames = {slot: frames[sid] for sid, slot in snapshot}
         with timer.stage("inference"):
@@ -444,10 +468,13 @@ class BatchedPerceptionEngine:
         return rec, outputs, payload
 
     # ---------------- pipelined (depth >= 2) path ----------------
-    def _tick_pipelined(self, frames, snapshot, active_mask, payload):
-        t0 = time.perf_counter()
-        slot_frames = {slot: frames[sid] for sid, slot in snapshot}
-        read_s = time.perf_counter() - t0
+    def _tick_pipelined(self, frames, snapshot, active_mask, payload, live):
+        with NO_SPAN if live is None else live.span(
+                "read", axis=STAGE_AXES["read"],
+                **self._span_tags(len(snapshot))):
+            t0 = time.perf_counter()
+            slot_frames = {slot: frames[sid] for sid, slot in snapshot}
+            read_s = time.perf_counter() - t0
         if self._exec.pending == 0:
             self._span_anchor = t0        # an idle engine starts a new burst
         # read_s rides the submission so the drained record carries ITS
@@ -457,23 +484,31 @@ class BatchedPerceptionEngine:
             payload=(snapshot, active_mask, payload, read_s))
         if not self._exec.ready():
             return None, {}, None          # pipeline still filling
-        return self._drain_one()
+        return self._drain_one(live)
 
-    def _drain_one(self):
+    def _drain_one(self, live):
         """Retire the oldest in-flight submission: single readback, host
         post, honest stage attribution for the overlapped phases —
         ``read`` is the drained tick's own frame prep, ``upload`` the
         host time its submit spent dispatching (H2D + launch),
         ``inference`` only the *residual* device wait the overlap failed
         to hide, ``post_processing`` the host pass over the single
-        readback."""
-        drained = self._exec.drain()
+        readback.  Live ``inference`` and ``post_processing`` spans wrap
+        the drain and the post; the drained tick's batch size rides on
+        the executor's ``readback`` span and on the post span."""
+        with NO_SPAN if live is None else live.span(
+                "inference", axis=STAGE_AXES["inference"],
+                **self._span_tags(None)):
+            drained = self._exec.drain()
         snapshot, active_mask, payload, read_s = drained.payload
-        t0 = time.perf_counter()
-        per_slot = self._post(drained.host, active_mask)
-        outputs: Dict[str, FrameOutput] = {
-            sid: per_slot[slot] for sid, slot in snapshot}
-        post_s = time.perf_counter() - t0
+        with NO_SPAN if live is None else live.span(
+                "post_processing", axis=STAGE_AXES["post_processing"],
+                **self._span_tags(len(snapshot))):
+            t0 = time.perf_counter()
+            per_slot = self._post(drained.host, active_mask)
+            outputs: Dict[str, FrameOutput] = {
+                sid: per_slot[slot] for sid, slot in snapshot}
+            post_s = time.perf_counter() - t0
         rec = StageRecord(stages={
             "read": read_s,
             "upload": drained.dispatch_s,
@@ -499,8 +534,9 @@ class BatchedPerceptionEngine:
         was in flight).  Used on churn (a rung bucket emptied) and at
         end of run so no frame is ever lost in the pipe."""
         out = []
+        live = self._live()
         while self._exec.pending:
-            out.append(self._drain_one())
+            out.append(self._drain_one(live))
         return out
 
     # ---------------- shared accounting ----------------
@@ -530,7 +566,7 @@ class BatchedPerceptionEngine:
         self.ticks += 1
         self.tick_log.append((n_served, lat))
         self.recorder.add(rec)
-        if self.obs is not None:
+        if self.obs is not None and self.stage_cost is not None:
             self._emit_tick_spans(rec, n_served, snapshot)
         for sid, _slot in snapshot:
             st = self.active.get(sid)
@@ -568,7 +604,9 @@ class BatchedPerceptionEngine:
 
     def _emit_tick_spans(self, rec: StageRecord, n_served: int,
                          snapshot) -> None:
-        """Lay this tick's stages on the observatory timeline.
+        """Lay this tick's modeled stages on the observatory timeline —
+        virtual time only: nothing ran, so no live span exists, and a
+        wall-clock tick records its spans live instead.
 
         The tick span ends at the tick's completion time — virtual time
         when replaying under a SimClock (``t_virtual`` was just stamped

@@ -42,6 +42,13 @@ output tree — the single-readback contract replacing the per-leaf
 on drain so callers can re-associate a result with the (stale) tick that
 produced it: at depth k, a drained result is k−1 ticks old.
 
+With an ``obs`` (a ``repro.obs.Observatory``) attached, ``submit`` opens
+live ``upload`` (the dirty slots' ``device_put`` calls) and ``dispatch``
+(assembly and step launch) spans and ``drain`` a ``readback`` span (the
+device wait and the one ``device_get``), and the tracer counts
+``h2d_bytes``, ``frames_submitted`` and ``d2h_bytes``.  The engine
+attaches it for wall-clock ticks only.
+
 **Fleet sharding** (``mesh=``): with a ``jax.sharding.Mesh`` carrying a
 ``data`` axis, the resident raw batch, the cached zero batch, and every
 program output carry a ``NamedSharding`` splitting the slot dim across
@@ -65,6 +72,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import data_shards, slot_batch_spec
+from repro.obs.span import NO_SPAN
 
 __all__ = ["Drained", "PipelinedExecutor"]
 
@@ -89,6 +97,7 @@ class _InFlight:
     payload: Any
     seq: int
     submitted_at: int             # submit counter value when enqueued
+    n_frames: int                 # dirty slots uploaded by its submit
     h2d_bytes: int
     dispatch_s: float
     t_submit: float
@@ -187,6 +196,7 @@ class PipelinedExecutor:
         self._raw = self._zeros_batch()
         self._queue: deque[_InFlight] = deque()
         self._seq = 0
+        self.obs = None               # live spans and counters when set
 
     def _zeros_batch(self):
         """A blank resident batch, carrying the mesh sharding when set."""
@@ -310,33 +320,45 @@ class PipelinedExecutor:
         """Dispatch one tick: upload the dirty slots, assemble the next
         resident batch, launch the fused step.  Never blocks on device
         work.  Returns the submission's sequence number."""
+        obs = self.obs
         t0 = time.perf_counter()
         dirty = np.zeros(self.capacity, bool)
         frames: list[Any] = [self._zero()] * self.capacity
         h2d = 0
-        for slot, frame in slot_frames.items():
-            if not 0 <= slot < self.capacity:
-                raise IndexError(
-                    f"slot {slot} out of range [0, {self.capacity})")
-            dirty[slot] = True
-            # explicit device_put so the H2D copy happens here, on the
-            # host thread, and is accounted — only dirty slots transfer
-            frames[slot] = self._put(self._checked(frame))
-            h2d += self.frame_bytes
+        with NO_SPAN if obs is None else obs.span(
+                "upload", batch_size=len(slot_frames), axis="io"):
+            for slot, frame in slot_frames.items():
+                if not 0 <= slot < self.capacity:
+                    raise IndexError(
+                        f"slot {slot} out of range [0, {self.capacity})")
+                dirty[slot] = True
+                # explicit device_put so the H2D copy happens here, on the
+                # host thread, and is accounted — only dirty slots transfer
+                frames[slot] = self._put(self._checked(frame))
+                h2d += self.frame_bytes
         n_dirty = int(dirty.sum())
-        if n_dirty == self.capacity:
-            self._raw = self._pack(*frames)
-        elif n_dirty:
-            # the mask crosses explicitly too: under the sentinel's
-            # jax.transfer_guard("disallow") an implicit numpy→device
-            # argument is an error, and the tick path must stay guard-clean
-            self._raw = self._assemble(
-                self._raw, self._put(dirty), *frames)
-        dev = self._step(self._raw)
+        # tvlint: disable=TV006 (the dispatch span times the async enqueue,
+        # not execution; the readback span in drain() holds the device wait)
+        with NO_SPAN if obs is None else obs.span(
+                "dispatch", batch_size=n_dirty, axis="runtime"):
+            if n_dirty == self.capacity:
+                self._raw = self._pack(*frames)
+            elif n_dirty:
+                # the mask crosses explicitly too: under the sentinel's
+                # jax.transfer_guard("disallow") an implicit numpy→device
+                # argument is an error, and the tick path must stay
+                # guard-clean
+                self._raw = self._assemble(
+                    self._raw, self._put(dirty), *frames)
+            dev = self._step(self._raw)
+        if obs is not None:
+            obs.tracer.add("h2d_bytes", h2d)
+            obs.tracer.add("frames_submitted", n_dirty)
         seq = self._seq
         self._seq += 1
         self._queue.append(_InFlight(
             dev=dev, payload=payload, seq=seq, submitted_at=self._seq,
+            n_frames=n_dirty,
             # tvlint: disable=TV006 (dispatch_s deliberately measures async
             # enqueue cost, not execution; drain() fences before latency_s)
             h2d_bytes=h2d, dispatch_s=time.perf_counter() - t0,
@@ -349,9 +371,15 @@ class PipelinedExecutor:
         if not self._queue:
             raise RuntimeError("drain() on an empty pipeline")
         entry = self._queue.popleft()
-        t0 = time.perf_counter()
-        host = jax.device_get(entry.dev)
-        t1 = time.perf_counter()
+        obs = self.obs
+        with NO_SPAN if obs is None else obs.span(
+                "readback", batch_size=entry.n_frames, axis="io"):
+            t0 = time.perf_counter()
+            host = jax.device_get(entry.dev)
+            t1 = time.perf_counter()
+        if obs is not None:
+            obs.tracer.add("d2h_bytes", sum(
+                leaf.nbytes for leaf in jax.tree.leaves(host)))
         return Drained(
             host=host, payload=entry.payload, seq=entry.seq,
             staleness=self._seq - entry.submitted_at,

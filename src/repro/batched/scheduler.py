@@ -33,6 +33,7 @@ from repro.core.stats import json_num
 from repro.anytime.ladder import Ladder, frame_quality
 from repro.bus.clock import SimClock
 from repro.distributed.sharding import data_shards
+from repro.obs.span import NO_SPAN
 from repro.perception.data import Scene, SceneConfig, generate_scene
 from repro.perception.pipelines import build_pipeline
 
@@ -142,7 +143,11 @@ class RungBucketScheduler:
         """Attach/detach an ``repro.obs.Observatory`` (pass None to
         detach).  Every rung engine emits its tick spans to it, tagged
         with the rung name; the scheduler itself emits ``rung_switch``
-        instants when a stream's controller migrates buckets."""
+        instants when a stream's controller migrates buckets.  On
+        wall-clock ticks the scheduler also opens live spans —
+        ``sched_tick`` around the tick, ``select``, ``seat`` per bucket
+        and ``account`` per drained engine tick — and every span the tick
+        opens, down to the executor's, carries this tick's id."""
         self.obs = obs
         for rung_name, eng in self.engines.items():
             eng.obs = obs
@@ -316,6 +321,11 @@ class RungBucketScheduler:
             pipeline_depth=float(self.depth),
         )
 
+    @property
+    def _live(self):
+        """The observatory that takes live spans: wall-clock ticks only."""
+        return self.obs if self.stage_cost is None else None
+
     def tick(self, scenes: Mapping[str, Scene],
              budgets: Optional[Mapping[str, float]] = None) -> TickResult:
         """Serve one frame for every stream in ``scenes``.
@@ -332,6 +342,12 @@ class RungBucketScheduler:
         members migrated away) are flushed so no frame is lost in the
         pipe.
         """
+        live = self._live
+        with NO_SPAN if live is None else live.span(
+                "sched_tick", tick=self.ticks, batch_size=len(scenes)):
+            return self._tick(scenes, budgets, live)
+
+    def _tick(self, scenes, budgets, live) -> TickResult:
         unknown = set(scenes) - set(self.streams)
         if unknown:
             raise KeyError(f"scenes for unknown streams: {sorted(unknown)}")
@@ -351,19 +367,20 @@ class RungBucketScheduler:
 
         # 1. every stream picks its rung for this tick
         buckets: Dict[str, list[str]] = {}
-        for sid, scene in scenes.items():
-            st = self.streams[sid]
-            budget = budgets[sid] if budgets is not None else st.budget_s
-            sel = st.controller.select(budget, self._features(st, scene))
-            rung_name = sel.rung.name
-            if self.obs is not None:
-                prev = self._prev_rung.get(sid)
-                if prev is not None and prev != rung_name:
-                    self.obs.tracer.instant(
-                        "rung_switch", stream=sid, tick=self.ticks,
-                        rung=rung_name, axis="model")
-                self._prev_rung[sid] = rung_name
-            buckets.setdefault(rung_name, []).append(sid)
+        with NO_SPAN if live is None else live.span("select", axis="model"):
+            for sid, scene in scenes.items():
+                st = self.streams[sid]
+                budget = budgets[sid] if budgets is not None else st.budget_s
+                sel = st.controller.select(budget, self._features(st, scene))
+                rung_name = sel.rung.name
+                if self.obs is not None:
+                    prev = self._prev_rung.get(sid)
+                    if prev is not None and prev != rung_name:
+                        self.obs.tracer.instant(
+                            "rung_switch", stream=sid, tick=self.ticks,
+                            rung=rung_name, axis="model")
+                    self._prev_rung[sid] = rung_name
+                buckets.setdefault(rung_name, []).append(sid)
 
         # 2. serve each bucket with one batched step
         latencies: Dict[str, float] = {}
@@ -373,45 +390,48 @@ class RungBucketScheduler:
         for rung_name in list(buckets):
             members = buckets[rung_name]
             eng = self.engines[rung_name]
-            # migrate membership: leave streams that moved away, join the
-            # ones that moved in (slot churn only — never a retrace)
-            for sid in [s for s in eng.active if s not in members]:
-                eng.leave(sid)
-            unseatable: list[str] = []
-            for sid in members:
-                if sid not in eng.active:
-                    shard = None
-                    if self.n_shards > 1:
-                        # fleet placement: seat on the shard whose
-                        # post-seating predicted cost is smallest
-                        try:
-                            shard = self.placer.place(
-                                rung_name, eng.shard_occupancy(),
-                                eng.slots_per_shard)
-                        except RuntimeError:
-                            if self.resilience is None:
-                                raise
-                            # no alive capacity: survivable under chaos —
-                            # the stream's frame drops this tick and the
-                            # join retries next tick
-                            self.streams[sid].drops += 1
-                            unseatable.append(sid)
-                            continue
-                    eng.join(sid, shard=shard)
-                    if (sid in self._pending_reseat
-                            and self.resilience is not None):
-                        # the deferred half of a shard evacuation lands
-                        self._pending_reseat.discard(sid)
-                        self.resilience.ledger.add(
-                            self.ticks, "failover",
-                            "re-seated after evacuation capacity pressure",
-                            stream=sid,
-                            shard=shard if shard is not None else -1)
-            if unseatable:
-                members = [s for s in members if s not in unseatable]
-                buckets[rung_name] = members
-                if not members:
-                    continue
+            with NO_SPAN if live is None else live.span(
+                    "seat", rung=rung_name, batch_size=len(members),
+                    axis="runtime"):
+                # migrate membership: leave streams that moved away, join the
+                # ones that moved in (slot churn only — never a retrace)
+                for sid in [s for s in eng.active if s not in members]:
+                    eng.leave(sid)
+                unseatable: list[str] = []
+                for sid in members:
+                    if sid not in eng.active:
+                        shard = None
+                        if self.n_shards > 1:
+                            # fleet placement: seat on the shard whose
+                            # post-seating predicted cost is smallest
+                            try:
+                                shard = self.placer.place(
+                                    rung_name, eng.shard_occupancy(),
+                                    eng.slots_per_shard)
+                            except RuntimeError:
+                                if self.resilience is None:
+                                    raise
+                                # no alive capacity: survivable under
+                                # chaos — the stream's frame drops this
+                                # tick and the join retries next tick
+                                self.streams[sid].drops += 1
+                                unseatable.append(sid)
+                                continue
+                        eng.join(sid, shard=shard)
+                        if (sid in self._pending_reseat
+                                and self.resilience is not None):
+                            # the deferred half of a shard evacuation lands
+                            self._pending_reseat.discard(sid)
+                            self.resilience.ledger.add(
+                                self.ticks, "failover",
+                                "re-seated after evacuation capacity pressure",
+                                stream=sid,
+                                shard=shard if shard is not None else -1)
+                if unseatable:
+                    members = [s for s in members if s not in unseatable]
+                    buckets[rung_name] = members
+                    if not members:
+                        continue
             # transient step faults: the resilience layer arms N failures;
             # each bucket step retries through them with exponential
             # backoff, aborting (bucket drops one tick) past max_retries
@@ -571,6 +591,15 @@ class RungBucketScheduler:
         """Account one drained engine tick: a cost-model observation at
         its (rung, batch-size), then per-stream quality/miss rows paired
         against the scenes and budgets echoed from its submission."""
+        live = self._live
+        with NO_SPAN if live is None else live.span(
+                "account", rung=rung_name,
+                batch_size=int(record.meta["batch_size"])):
+            self._account_rows(rung_name, record, outs, echoed,
+                               latencies, outputs, rows)
+
+    def _account_rows(self, rung_name, record, outs, echoed,
+                      latencies, outputs, rows) -> None:
         lat = record.end_to_end
         # the deadline contract is judged on frame completion latency:
         # for sync ticks that IS the tick latency; for pipelined ticks it

@@ -29,6 +29,9 @@ __all__ = [
     "STAGE_AXES",
 ]
 
+# a stage timed with no tracer enters this in place of a span
+_NO_SPAN = contextlib.nullcontext()
+
 # Canonical stage names from the paper's Fig. 3 timeline.
 READ = "read"
 PRE = "pre_processing"
@@ -168,11 +171,13 @@ class StageTimer:
         timer.note("num_objects", n)
         rec.add(timer.finish())
 
-    With ``tracer=`` (a ``repro.obs.SpanTracer``, duck-typed) every
-    closed interval is also forwarded as a span carrying ``tags``
+    With ``tracer=`` (anything with the ``span`` context manager of
+    ``repro.obs.SpanTracer`` or ``repro.obs.Observatory``, duck-typed)
+    every stage runs inside a live span carrying ``tags``
     (stream/tick/rung/batch_size/track) and the stage's default axis, so
-    stage timing lands on the unified timeline without a second clock
-    read — there is exactly one recording path."""
+    spans the stage's body opens nest under it — there is exactly one
+    recording path.  The record's sums come from this timer's own clock
+    either way."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter,
                  tracer: Any = None,
@@ -184,18 +189,15 @@ class StageTimer:
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            t1 = self._clock()
-            self._record.stages[name] = (
-                self._record.stages.get(name, 0.0) + t1 - t0
-            )
-            if self._tracer is not None:
-                self._tracer.record(
-                    name, t0, t1,
-                    axis=STAGE_AXES.get(name, "end_to_end"), **self._tags
+        with (_NO_SPAN if self._tracer is None else self._tracer.span(
+                name, axis=STAGE_AXES.get(name, "end_to_end"), **self._tags)):
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                t1 = self._clock()
+                self._record.stages[name] = (
+                    self._record.stages.get(name, 0.0) + t1 - t0
                 )
 
     def note(self, key: str, value: float) -> None:

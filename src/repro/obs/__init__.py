@@ -5,7 +5,9 @@ every subsystem so a cross-stream timeline and per-axis variance report
 exist for any run:
 
 * :mod:`repro.obs.span` — preallocated ring-buffer span tracer with an
-  injected (SimClock-compatible) clock;
+  injected (SimClock-compatible) clock; live spans also annotate the
+  JAX profiler's trace as ``repro.<name>``, and the tracer keeps
+  cumulative counters;
 * :mod:`repro.obs.sketch` — P² and mergeable log-histogram quantile
   sketches;
 * :mod:`repro.obs.metrics` — Welford+sketch per (stream, stage, rung,
@@ -19,7 +21,10 @@ exist for any run:
 :class:`Observatory` bundles the pieces and is the object the engines
 accept as ``obs=``.  It is pure observation: attaching one never changes
 scheduling, rung choice, or replay output (the golden byte-identity test
-holds with tracing on).
+holds with tracing on).  In wall-clock serving the scheduler, engines and
+executor open live spans through :meth:`Observatory.span`; under a
+replay's virtual time nothing runs for real, and the engines lay their
+modeled stage times on the timeline with :meth:`Observatory.record`.
 """
 from __future__ import annotations
 
@@ -33,13 +38,17 @@ from repro.obs.export import (to_chrome_trace, validate_chrome_trace,
                               write_chrome_trace)
 from repro.obs.metrics import MetricKey, MetricsHub, StageMetrics
 from repro.obs.sketch import LatencySketch, P2Quantile
-from repro.obs.span import DEFAULT_CAPACITY, Span, SpanTracer
+from repro.obs.span import (DEFAULT_CAPACITY, NO_SPAN, PROFILER_PREFIX,
+                            LiveSpan, Span, SpanTracer)
 
 __all__ = [
     "Observatory",
     "Span",
     "SpanTracer",
+    "LiveSpan",
     "DEFAULT_CAPACITY",
+    "NO_SPAN",
+    "PROFILER_PREFIX",
     "P2Quantile",
     "LatencySketch",
     "MetricKey",
@@ -89,6 +98,14 @@ class Observatory:
         span = self.tracer.record(*args, **kwargs)
         self.emit(span)
         return span
+
+    def span(self, name: str, **tags) -> LiveSpan:
+        """Context manager: ``tracer.span`` around the block, then the
+        closed span fed to the metrics hub, as :meth:`record` feeds a
+        measured one."""
+        live = self.tracer.span(name, **tags)
+        live.sink = self.emit
+        return live
 
     def sample(self, frame: FrameSample) -> None:
         """Log one served frame for later axis attribution."""

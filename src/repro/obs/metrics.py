@@ -97,6 +97,9 @@ class MetricsHub:
                  n_bins: int = 2048) -> None:
         self._params = (lo, gamma, n_bins)
         self._by_key: dict[MetricKey, StageMetrics] = {}
+        # the span feed's index by plain tuple: live spans arrive every
+        # few microseconds, and a tuple hashes without a MetricKey built
+        self._by_tags: dict[tuple, StageMetrics] = {}
 
     def _slot(self, key: MetricKey) -> StageMetrics:
         m = self._by_key.get(key)
@@ -110,8 +113,11 @@ class MetricsHub:
         self._slot(MetricKey(stream, stage, rung, batch_size)).update(value)
 
     def observe_span(self, span: Span) -> None:
-        self._slot(MetricKey(span.stream, span.name, span.rung,
-                             span.batch_size)).update(span.duration)
+        tags = (span.stream, span.name, span.rung, span.batch_size)
+        m = self._by_tags.get(tags)
+        if m is None:
+            m = self._by_tags[tags] = self._slot(MetricKey(*tags))
+        m.update(span.t1 - span.t0)
 
     def get(self, key: MetricKey) -> Optional[StageMetrics]:
         return self._by_key.get(key)

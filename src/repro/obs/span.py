@@ -10,7 +10,7 @@ parallel rows in Perfetto.
 
 Design constraints, in order:
 
-* **Low overhead** — recording a span is one clock read, a dataclass
+* **Low overhead** — recording a span is one clock read, a tuple
   construction, and a ring-buffer slot write under a lock.  The ring is
   preallocated (a fixed-length list), so the steady state allocates no
   container storage and never triggers list growth; ``benchmarks/
@@ -22,26 +22,44 @@ Design constraints, in order:
   a ``SimClock`` every timestamp is virtual, so scenario-replay traces
   are byte-reproducible and tracing can never perturb a replay
   decision (the tracer only ever *reads* the clock).
+* **On the profiler's clock too** — a live :meth:`SpanTracer.span` also
+  opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``
+  around its body.  While a profiler session runs, the profiler stamps
+  it on the clock of the device planes, so device idle time can be put
+  down to the program span the host was in; the ring keeps the
+  interval on the tracer's own clock, read inside the same annotation.
+
+Beside spans the tracer keeps cumulative counters (``add``/
+``counters``): bytes and frames counted at the same layer boundaries.
 """
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import threading
 import time
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 
 from repro.analysis.findings import AXES
 
-__all__ = ["Span", "SpanTracer", "DEFAULT_CAPACITY"]
+__all__ = ["Span", "SpanTracer", "LiveSpan", "DEFAULT_CAPACITY",
+           "PROFILER_PREFIX", "NO_SPAN"]
 
 DEFAULT_CAPACITY = 65536
+PROFILER_PREFIX = "repro."     # profiler name of a live span: repro.<name>
+
+# what a call site enters in place of a span when no tracer is attached:
+# ``with NO_SPAN if obs is None else obs.span(...)`` costs one test
+NO_SPAN = contextlib.nullcontext()
+
+# tags a live span inherits from the span enclosing it when not given:
+# stream, tick, rung, batch_size, track, shard, at a top-level span's
+# defaults
+_TAG_DEFAULTS = ("", 0, "", 0, 0, -1)
 
 
-@dataclasses.dataclass(frozen=True)
-class Span:
+class Span(NamedTuple):
     """One timed interval with variance-attribution tags.
 
     ``seq`` is assigned at *open* time (so a parent's id is known to its
@@ -51,6 +69,9 @@ class Span:
     ticks onto parallel renderer rows (tid in the Chrome trace).
     ``shard`` is the data-shard id serving the interval on a fleet mesh
     (``-1`` = not shard-specific / single-device).
+
+    An immutable tuple: a live span builds one on the serving path, at
+    a fraction of a frozen dataclass's cost.
     """
 
     name: str
@@ -71,7 +92,7 @@ class Span:
         return self.t1 - self.t0
 
     def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
+        return self._asdict()
 
 
 class SpanTracer:
@@ -105,7 +126,8 @@ class SpanTracer:
         self._ring: list[Optional[Span]] = [None] * capacity
         self._n = 0                    # spans ever recorded (close order)
         self._next_seq = 0             # ids handed out (open order)
-        self._open: list[int] = []     # seq stack of open spans
+        self._open: list[LiveSpan] = []   # stack of open live spans
+        self._counters: dict[str, float] = {}
         self._lock = threading.Lock()
 
     # ---------------- recording ----------------
@@ -133,62 +155,41 @@ class SpanTracer:
             seq = self._next_seq
             self._next_seq += 1
             if parent is None:
-                parent = self._open[-1] if self._open else -1
+                parent = self._open[-1].seq if self._open else -1
             span = Span(name=name, t0=t0, t1=t1, stream=stream, tick=tick,
                         rung=rung, batch_size=batch_size, axis=axis,
                         track=track, parent=parent, seq=seq, shard=shard)
-            self._ring[self._n % self.capacity] = span
-            self._n += 1
+            self._write(span)
         return span
 
-    @contextlib.contextmanager
     def span(
         self,
         name: str,
         *,
-        stream: str = "",
-        tick: int = 0,
-        rung: str = "",
-        batch_size: int = 0,
+        stream: Optional[str] = None,
+        tick: Optional[int] = None,
+        rung: Optional[str] = None,
+        batch_size: Optional[int] = None,
         axis: str = "end_to_end",
-        track: int = 0,
-        shard: int = -1,
+        track: Optional[int] = None,
+        shard: Optional[int] = None,
         fence: Any = None,
-    ) -> Iterator[None]:
+    ) -> "LiveSpan":
         """Context-managed span with nesting (children see this span as
-        their ``parent``).  ``fence`` — a device value or a zero-arg
-        callable returning one (evaluated at exit, so it can name a
-        value assigned inside the block) — is blocked on before the
-        interval closes so async dispatch cannot leak out of the
-        measurement."""
+        their ``parent``).  A tag left out (``None``) is inherited from
+        the enclosing open span, or takes the ``Span`` default at top
+        level, so a layer need not know the tick or rung it serves.
+        ``fence`` — a device value or a zero-arg callable returning one
+        (evaluated at exit, so it can name a value assigned inside the
+        block) — is blocked on before the interval closes so async
+        dispatch cannot leak out of the measurement.  The block runs
+        inside a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``;
+        after exit the context manager's ``span`` is the recorded
+        :class:`Span`."""
         if axis not in AXES:
             raise ValueError(f"unknown axis {axis!r}; axes: {AXES}")
-        with self._lock:
-            seq = self._next_seq
-            self._next_seq += 1
-            parent = self._open[-1] if self._open else -1
-            self._open.append(seq)
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            if fence is not None:
-                jax.block_until_ready(fence() if callable(fence) else fence)
-            t1 = self.clock()
-            with self._lock:
-                if self._open and self._open[-1] == seq:
-                    self._open.pop()
-                else:                  # out-of-order close (generator abuse)
-                    try:
-                        self._open.remove(seq)
-                    except ValueError:
-                        pass
-                span = Span(name=name, t0=t0, t1=t1, stream=stream,
-                            tick=tick, rung=rung, batch_size=batch_size,
-                            axis=axis, track=track, parent=parent, seq=seq,
-                            shard=shard)
-                self._ring[self._n % self.capacity] = span
-                self._n += 1
+        return LiveSpan(self, name, axis, fence,
+                        (stream, tick, rung, batch_size, track, shard))
 
     def instant(self, name: str, **tags) -> Span:
         """Zero-duration event (rung switch, admission decision, backend
@@ -223,3 +224,84 @@ class SpanTracer:
             self._ring = [None] * self.capacity
             self._n = 0
             self._open.clear()
+            self._counters.clear()
+
+    # ---------------- counters ----------------
+    def add(self, name: str, value: float) -> None:
+        """Add ``value`` to the cumulative counter ``name``."""
+        with self._lock:
+            self._counters[name] = self._counters.get(name, 0) + value
+
+    def counters(self) -> dict[str, float]:
+        """A copy of every counter's running total."""
+        with self._lock:
+            return dict(self._counters)
+
+    # ---------------- internals ----------------
+    def _write(self, span: Span) -> None:
+        """Ring-slot write; the caller holds the lock."""
+        self._ring[self._n % self.capacity] = span
+        self._n += 1
+
+
+class LiveSpan:
+    """One :meth:`SpanTracer.span` in flight: a profiler annotation around
+    an interval on the tracer's clock.  ``span`` is the recorded
+    :class:`Span` once the block has exited."""
+
+    __slots__ = ("_tracer", "_note", "name", "axis", "fence", "tags",
+                 "seq", "parent", "t0", "span", "sink")
+
+    def __init__(self, tracer: SpanTracer, name: str, axis: str,
+                 fence: Any, tags: tuple) -> None:
+        self._tracer = tracer
+        self._note = jax.profiler.TraceAnnotation(PROFILER_PREFIX + name)
+        self.name = name
+        self.axis = axis
+        self.fence = fence
+        self.tags = tags
+        self.span: Optional[Span] = None
+        # called with the recorded span once the block has exited
+        self.sink: Optional[Callable[[Span], None]] = None
+
+    def __enter__(self) -> "LiveSpan":
+        tr = self._tracer
+        self._note.__enter__()
+        self.t0 = tr.clock()
+        with tr._lock:
+            self.seq = tr._next_seq
+            tr._next_seq += 1
+            outer = tr._open[-1] if tr._open else None
+            self.parent = -1 if outer is None else outer.seq
+            base = _TAG_DEFAULTS if outer is None else outer.tags
+            self.tags = [b if v is None else v
+                         for v, b in zip(self.tags, base)]
+            tr._open.append(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        tr = self._tracer
+        try:
+            if self.fence is not None:
+                fence = self.fence
+                jax.block_until_ready(fence() if callable(fence) else fence)
+        finally:
+            t1 = tr.clock()
+            self._note.__exit__(exc_type, exc, tb)
+            stream, tick, rung, batch_size, track, shard = self.tags
+            span = Span(self.name, self.t0, t1, stream, tick, rung,
+                        batch_size, self.axis, track, self.parent, self.seq,
+                        shard)
+            with tr._lock:
+                if tr._open and tr._open[-1] is self:
+                    tr._open.pop()
+                else:                  # out-of-order close
+                    try:
+                        tr._open.remove(self)
+                    except ValueError:
+                        pass
+                tr._write(span)
+            self.span = span
+        if self.sink is not None:
+            self.sink(span)
+        return False
