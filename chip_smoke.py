@@ -176,10 +176,10 @@ def _agreement(frames, label: str, log) -> float:
 def _check_traces(sched, what: str) -> None:
     for name, eng in sched.engines.items():
         counts = (eng.trace_count, eng.assemble_trace_count,
-                  eng.pack_trace_count, eng.update_trace_count)
-        if counts != (1, 1, 1, 1):
+                  eng.update_trace_count)
+        if counts != (1, 1, 1):
             raise AssertionError(
-                f"{what}: rung {name} traced step/assemble/pack/update "
+                f"{what}: rung {name} traced step/assemble/update "
                 f"{counts} times, expected once each")
 
 

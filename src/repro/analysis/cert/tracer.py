@@ -214,7 +214,7 @@ def certify_rung(point: RungPoint, env: InputEnvelope,
             seated.append(sid)
             ctx(f"occ{occ}/tick_after_rejoin")
             eng.tick({s: frame for s in seated})
-    # the scheduler's calibration probe (pack + step + carve-out avals)
+    # the scheduler's calibration probe (all-dirty upload + step avals)
     ctx("probe")
     eng.probe([frame])
 

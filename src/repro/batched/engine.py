@@ -201,10 +201,6 @@ class BatchedPerceptionEngine:
         return self._exec.assemble_traces
 
     @property
-    def pack_trace_count(self) -> int:
-        return self._exec.pack_traces
-
-    @property
     def update_trace_count(self) -> int:
         return self._exec.update_traces
 

@@ -36,6 +36,29 @@ engine's ``trace_count``):
   zeroing, probes).  These run at churn frequency, not tick frequency,
   where donation's in-place write is worth its synchronization.
 
+**All-dirty upload** (every slot has a frame this tick, the steady state
+of a full fleet): the frames are stacked on the host into one contiguous
+``(capacity, H, W, 3)`` float32 staging batch and cross in ONE
+``jax.device_put`` that lands directly as the resident batch, with the
+batch sharding — no per-slot puts and no program launch before the
+step.  Which path a tick takes is decided by what ``submit`` observes
+(every slot dirty or not), not by a knob; the step receives the same
+values, shape and sharding either way, so its outputs are bitwise
+identical.  A partial tick keeps the per-slot puts and ``assemble``, so
+churned, skipped or half-seated ticks still upload only their dirty
+bytes.  Staging safety: ``depth + 1`` staging batches, allocated once
+and reused round robin (a fresh array a tick would fault in every host
+page it fills), each with a fence: the device values that may still
+read it.  On an accelerator the transfer may still be in flight when
+``submit`` returns; on the CPU backend ``device_put`` may alias the
+(64-byte aligned) batch, so every value computed from the resident
+batch while it may alias joins the fence.  A staging batch is rewritten
+only once its fence is ready, which in steady state it already is: the
+work that read it drained ``depth`` ticks ago.  The stack copies the
+frames, so the caller may reuse its frame buffers as soon as ``submit``
+returns.  ``warmup`` and ``run_direct`` put a fresh array, leaving the
+staging batches to the pipeline.
+
 Results drain oldest-first with ONE ``jax.device_get`` of the whole
 output tree — the single-readback contract replacing the per-leaf
 ``np.asarray`` walks.  ``payload`` riding on each submit is echoed back
@@ -43,20 +66,23 @@ on drain so callers can re-associate a result with the (stale) tick that
 produced it: at depth k, a drained result is k−1 ticks old.
 
 With an ``obs`` (a ``repro.obs.Observatory``) attached, ``submit`` opens
-live ``upload`` (the dirty slots' ``device_put`` calls) and ``dispatch``
-(assembly and step launch) spans and ``drain`` a ``readback`` span (the
-device wait and the one ``device_get``), and the tracer counts
-``h2d_bytes``, ``frames_submitted`` and ``d2h_bytes``.  The engine
+live ``upload`` (the dirty frames' ``device_put`` calls: one stacked
+batch when every slot is dirty) and ``dispatch`` (assembly and step
+launch) spans and ``drain`` a ``readback`` span (the device wait and the
+one ``device_get``), and the tracer counts ``h2d_bytes``,
+``h2d_transfers`` (the ``device_put`` calls a submit issues),
+``frames_submitted`` and ``d2h_bytes``.  The engine
 attaches it for wall-clock ticks only.
 
 **Fleet sharding** (``mesh=``): with a ``jax.sharding.Mesh`` carrying a
-``data`` axis, the resident raw batch, the cached zero batch, and every
-program output carry a ``NamedSharding`` splitting the slot dim across
-the mesh — shard *k* owns the contiguous slot block
+``data`` axis, the resident raw batch, the cached zero batch, the all-dirty
+upload, and every program output carry a ``NamedSharding`` splitting the
+slot dim across the mesh — shard *k* owns the contiguous slot block
 ``[k·capacity/K, (k+1)·capacity/K)``.  The programs themselves are
 unchanged (GSPMD partitions them from the declared ``out_shardings``),
 so trace counts, donation, and the dirty-slot upload contract all hold
-per shard exactly as on one device; a 1-device mesh is the identical
+per shard exactly as on one device (the all-dirty put hands each shard
+its own slot block in the same call); a 1-device mesh is the identical
 program and bitwise-identical outputs.
 """
 from __future__ import annotations
@@ -75,6 +101,25 @@ from repro.distributed.sharding import data_shards, slot_batch_spec
 from repro.obs.span import NO_SPAN
 
 __all__ = ["Drained", "PipelinedExecutor"]
+
+
+def _host_batch(shape) -> np.ndarray:
+    """A zeroed float32 host array on a 64-byte boundary, where the CPU
+    backend's ``device_put`` reads it in place instead of copying it, its
+    pages faulted in now rather than in a tick's stack."""
+    n = int(np.prod(shape)) * 4
+    raw = np.empty(n + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    out = raw[off:off + n].view(np.float32).reshape(shape)
+    out.fill(0.0)
+    return out
+
+
+def _unfinished(values) -> list:
+    """The device arrays among ``values`` still being computed (a donated,
+    deleted one was fenced through the value it was donated to)."""
+    return [x for x in jax.tree.leaves(values)
+            if not x.is_deleted() and not x.is_ready()]
 
 
 @dataclasses.dataclass
@@ -118,9 +163,8 @@ class PipelinedExecutor:
     # static certifier (repro.analysis.cert) enumerates PROGRAMS to
     # instrument them and cross-checks DONATED_ARGNUMS against the
     # donated_invars the traced jaxpr actually carries.
-    PROGRAMS = ("step", "assemble", "pack", "slot_update")
-    DONATED_ARGNUMS = {"step": (), "assemble": (), "pack": (),
-                       "slot_update": (0,)}
+    PROGRAMS = ("step", "assemble", "slot_update")
+    DONATED_ARGNUMS = {"step": (), "assemble": (), "slot_update": (0,)}
 
     def __init__(
         self,
@@ -155,7 +199,6 @@ class PipelinedExecutor:
         # shapes are supposed to rule out — is observable
         self.step_traces = 0
         self.assemble_traces = 0
-        self.pack_traces = 0
         self.update_traces = 0
 
         def counted_step(raw):
@@ -166,14 +209,6 @@ class PipelinedExecutor:
             self.assemble_traces += 1
             return jnp.where(dirty[:, None, None, None], jnp.stack(frames), raw)
 
-        def counted_pack(*frames):
-            # all-capacity-dirty fast path: every slot is replaced, so the
-            # select against the previous batch is pure overhead — a plain
-            # stack produces bitwise-identical values with half the
-            # device-side traffic (the 8-streams-on-8-slots steady state)
-            self.pack_traces += 1
-            return jnp.stack(frames)
-
         def counted_update(raw, slot, frame):
             self.update_traces += 1
             return raw.at[slot].set(frame)
@@ -181,19 +216,26 @@ class PipelinedExecutor:
         # in mesh mode, pin every program's output to the slot-dim
         # sharding (a single sharding is a tree prefix, so the step's
         # whole output tree — every leaf leads with the slot dim — shards
-        # identically); without it, pack's stack of replicated per-frame
-        # uploads would leave the resident batch replicated and the fused
-        # step unpartitioned
+        # identically); without it, assemble's stack of replicated
+        # per-frame uploads would leave the resident batch replicated and
+        # the fused step unpartitioned
         shard_kw = ({"out_shardings": self._batch_sharding}
                     if self._batch_sharding is not None else {})
         self._step = jax.jit(counted_step, **shard_kw)
         self._assemble = jax.jit(counted_assemble, **shard_kw)
-        self._pack = jax.jit(counted_pack, **shard_kw)
         # donation: carve-outs mutate the resident batch in place
         self._slot_update = jax.jit(counted_update, donate_argnums=(0,),
                                     **shard_kw)
         self._zero_frame = None       # cached device zeros, made lazily
         self._raw = self._zeros_batch()
+        # all-dirty staging: host batches the frames are stacked into,
+        # reused round robin, one more than the pipeline's depth, so the
+        # work that read a batch has drained before it comes round; each
+        # keeps the device values that may still read it (its fence)
+        self._staging: list[np.ndarray] = []      # made by the first use
+        self._stage_fence: list[list] = []
+        self._stage_next = 0
+        self._raw_stage: Optional[int] = None     # staging _raw may alias
         self._queue: deque[_InFlight] = deque()
         self._seq = 0
         self.obs = None               # live spans and counters when set
@@ -234,6 +276,45 @@ class PipelinedExecutor:
             return jax.device_put(x, self._replicated)
         return jax.device_put(x)
 
+    def _put_batch(self, frames, staged: bool = False) -> tuple[Any, int]:
+        """ONE host→device transfer of a whole batch: the checked frames,
+        one per slot, stacked into one contiguous host array that lands
+        with the resident batch's sharding (each mesh shard receives its
+        own slot block).  ``staged`` stacks into the next staging batch,
+        once every device value that may still read it is ready (an
+        in-flight transfer reads it; a CPU ``device_put`` may alias it);
+        otherwise into a fresh array.  Returns the device batch and the
+        staging index (-1 for a fresh array)."""
+        frames = [self._checked(f) for f in frames]
+        if not staged:
+            return self._device_batch(np.stack(frames)), -1
+        if not self._staging:
+            shape = (self.capacity, *self.image_shape)
+            self._staging = [_host_batch(shape)
+                             for _ in range(self.depth + 1)]
+            self._stage_fence = [[] for _ in self._staging]
+        i = self._stage_next
+        self._stage_next = (i + 1) % len(self._staging)
+        jax.block_until_ready(_unfinished(self._stage_fence[i]))
+        buf = self._staging[i]
+        np.stack(frames, out=buf)
+        batch = self._device_batch(buf)
+        self._stage_fence[i] = [batch]
+        return batch, i
+
+    def _device_batch(self, batch: np.ndarray) -> Any:
+        if self._batch_sharding is not None:
+            return jax.device_put(batch, self._batch_sharding)
+        return jax.device_put(batch)
+
+    def _reads_raw(self, value) -> None:
+        """Fence a device value dispatched over the resident batch: while
+        that batch may alias a staging batch, the staging batch is not
+        rewritten until the value is ready."""
+        if self._raw_stage is not None:
+            fence = self._stage_fence[self._raw_stage]
+            fence[:] = _unfinished(fence) + [value]
+
     def _zero(self):
         if self._zero_frame is None:
             self._zero_frame = self._put(
@@ -262,14 +343,17 @@ class PipelinedExecutor:
         f = self._zero() if frame is None else self._checked(frame)
         # slot index as a device int32 (matching warmup's aval) so the
         # carve-out is also clean under jax.transfer_guard("disallow")
+        # donation may keep the staging alias, so the fence follows it
         self._raw = self._slot_update(
             self._raw, self._put(np.int32(slot)),
             self._put(f) if isinstance(f, np.ndarray) else f)
+        self._reads_raw(self._raw)
 
     def reset(self) -> None:
         """Drop all in-flight work and blank the resident batch."""
         self._queue.clear()
         self._raw = self._zeros_batch()
+        self._raw_stage = None
 
     def warmup(self) -> None:
         """Trace + compile every jitted program on throwaway buffers so
@@ -279,13 +363,15 @@ class PipelinedExecutor:
         callers' warmups.  Resident slot contents are untouched."""
         # sharded like the live resident batch, so the warmed executables
         # are exactly the ones the tick path replays (jit caches on input
-        # shardings as well as avals)
-        zeros = self._zeros_batch()
-        raw = self._assemble(zeros,
+        # shardings as well as avals): the step over an assembled batch
+        # (partial ticks) and over an all-dirty upload
+        zero = np.zeros(self.image_shape, np.float32)
+        raw = self._assemble(self._zeros_batch(),
                              self._put(np.zeros(self.capacity, bool)),
                              *[self._zero()] * self.capacity)
-        self._pack(*[self._zero()] * self.capacity)
         jax.block_until_ready(self._step(raw))
+        batch, _ = self._put_batch([zero] * self.capacity)
+        jax.block_until_ready(self._step(batch))
         # same avals as set_slot's call (device int32 slot), so the carve
         #-out path warms exactly the executable set_slot will replay
         self._slot_update(self._zeros_batch(), self._put(np.int32(0)),
@@ -294,14 +380,16 @@ class PipelinedExecutor:
     def run_direct(self, frames=None):
         """One blocking fused step *outside* the pipeline (calibration
         probes): over the resident batch (``frames is None``, read-only)
-        or over a throwaway batch packed from ``frames`` cycled across
-        the slots.  Returns the device outputs, ready."""
+        or over a throwaway batch of ``frames`` cycled across the slots,
+        uploaded in one transfer as an all-dirty tick's is (from a fresh
+        host array: the staging batches belong to the pipeline).
+        Returns the device outputs, ready."""
         if frames is None:
             dev = self._step(self._raw)
         else:
-            put = [self._put(self._checked(frames[b % len(frames)]))
-                   for b in range(self.capacity)]
-            dev = self._step(self._pack(*put))
+            batch, _ = self._put_batch(
+                [frames[b % len(frames)] for b in range(self.capacity)])
+            dev = self._step(batch)
         jax.block_until_ready(dev)
         return dev
 
@@ -318,41 +406,54 @@ class PipelinedExecutor:
     def submit(self, slot_frames: Mapping[int, np.ndarray],
                payload: Any = None) -> int:
         """Dispatch one tick: upload the dirty slots, assemble the next
-        resident batch, launch the fused step.  Never blocks on device
-        work.  Returns the submission's sequence number."""
+        resident batch, launch the fused step.  With every slot dirty the
+        frames cross in one stacked transfer that becomes the resident
+        batch itself (no assembly).  Never blocks on device work.
+        Returns the submission's sequence number."""
         obs = self.obs
         t0 = time.perf_counter()
-        dirty = np.zeros(self.capacity, bool)
-        frames: list[Any] = [self._zero()] * self.capacity
-        h2d = 0
+        for slot in slot_frames:
+            if not 0 <= slot < self.capacity:
+                raise IndexError(
+                    f"slot {slot} out of range [0, {self.capacity})")
+        n_dirty = len(slot_frames)
+        all_dirty = n_dirty == self.capacity
+        h2d = n_dirty * self.frame_bytes
         with NO_SPAN if obs is None else obs.span(
-                "upload", batch_size=len(slot_frames), axis="io"):
-            for slot, frame in slot_frames.items():
-                if not 0 <= slot < self.capacity:
-                    raise IndexError(
-                        f"slot {slot} out of range [0, {self.capacity})")
-                dirty[slot] = True
-                # explicit device_put so the H2D copy happens here, on the
-                # host thread, and is accounted — only dirty slots transfer
-                frames[slot] = self._put(self._checked(frame))
-                h2d += self.frame_bytes
-        n_dirty = int(dirty.sum())
+                "upload", batch_size=n_dirty, axis="io"):
+            # explicit device_puts so the H2D copy happens here, on the
+            # host thread, and is accounted — only dirty slots transfer
+            if all_dirty:
+                self._raw, self._raw_stage = self._put_batch(
+                    [slot_frames[b] for b in range(self.capacity)],
+                    staged=True)
+                transfers = 1
+            else:
+                dirty = np.zeros(self.capacity, bool)
+                frames: list[Any] = [self._zero()] * self.capacity
+                for slot, frame in slot_frames.items():
+                    dirty[slot] = True
+                    frames[slot] = self._put(self._checked(frame))
+                transfers = n_dirty
         # tvlint: disable=TV006 (the dispatch span times the async enqueue,
         # not execution; the readback span in drain() holds the device wait)
         with NO_SPAN if obs is None else obs.span(
                 "dispatch", batch_size=n_dirty, axis="runtime"):
-            if n_dirty == self.capacity:
-                self._raw = self._pack(*frames)
-            elif n_dirty:
+            if n_dirty and not all_dirty:
                 # the mask crosses explicitly too: under the sentinel's
                 # jax.transfer_guard("disallow") an implicit numpy→device
                 # argument is an error, and the tick path must stay
                 # guard-clean
                 self._raw = self._assemble(
                     self._raw, self._put(dirty), *frames)
+                self._reads_raw(self._raw)
+                self._raw_stage = None
+                transfers += 1
             dev = self._step(self._raw)
+            self._reads_raw(dev)
         if obs is not None:
             obs.tracer.add("h2d_bytes", h2d)
+            obs.tracer.add("h2d_transfers", transfers)
             obs.tracer.add("frames_submitted", n_dirty)
         seq = self._seq
         self._seq += 1

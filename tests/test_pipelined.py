@@ -1,14 +1,18 @@
 """Pipelined device-resident executor: correctness of the depth-k
 pipeline (bitwise identity with the synchronous path, staleness
 semantics, slot churn mid-pipeline, single-trace invariants, dirty-slot
-H2D accounting), the pipelined-latency cost-model mode, scheduler depth
-wiring, and the golden byte-identity regression for the refactored
-sync engine.
+H2D accounting, the one-transfer all-dirty upload), the
+pipelined-latency cost-model mode, scheduler depth wiring, and the
+golden byte-identity regression for the refactored sync engine.
 """
 import json
 import os
+import subprocess
+import sys
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,7 +21,9 @@ from repro.anytime import Rung, calibrate
 from repro.anytime.controller import ContractController, ControllerConfig
 from repro.anytime.cost import RungCostModel, SceneFeatures
 from repro.batched import BatchedPerceptionEngine, PipelinedExecutor, RungBucketScheduler
+from repro.batched.engine import fused_batch_program
 from repro.core.timing import StageRecord
+from repro.obs import Observatory
 from repro.perception import SceneConfig, build_pipeline, generate_scene
 
 CITY = SceneConfig("city", seed=33)
@@ -221,6 +227,211 @@ def test_executor_pipeline_order_and_staleness():
     assert drains[0].staleness == 2                    # waited out 2 submits
     # the step saw each tick's slot content
     assert [float(d.host[0]) for d in drains] == [0.0, 48.0, 96.0]
+
+
+# ------------------------------------- all-dirty upload: one transfer -----
+def _count_device_puts(monkeypatch) -> list:
+    """Record every ``jax.device_put`` the executor issues from here on."""
+    calls = []
+    real = jax.device_put
+
+    def counting(x, *args, **kwargs):
+        calls.append(x)
+        return real(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", counting)
+    return calls
+
+
+def _trees_equal(a, b) -> bool:
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    return len(la) == len(lb) and all(
+        np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(la, lb))
+
+
+def _rung_executor(capacity, image_shape, depth=1, name="early_exit"):
+    built = build_pipeline(name)
+    return PipelinedExecutor(fused_batch_program(built), capacity,
+                             image_shape, depth=depth)
+
+
+def test_all_dirty_submit_is_one_transfer(monkeypatch):
+    """Every slot dirty: the frames cross in ONE device_put of the
+    stacked batch; a partial tick still puts each dirty frame and the
+    mask.  Bytes are the dirty frames' either way."""
+    ex = PipelinedExecutor(lambda raw: raw.sum(axis=(1, 2, 3)), 4,
+                           (8, 8, 3))
+    ex.obs = Observatory()
+    ex.warmup()
+    calls = _count_device_puts(monkeypatch)
+    ex.submit({s: np.full((8, 8, 3), s, np.float32) for s in range(4)})
+    assert len(calls) == 1
+    assert calls[0].shape == (4, 8, 8, 3)
+    counters = ex.obs.tracer.counters()
+    assert counters["h2d_transfers"] == 1
+    assert counters["h2d_bytes"] == 4 * ex.frame_bytes
+    drained = ex.drain()
+    assert drained.h2d_bytes == 4 * ex.frame_bytes
+    assert [float(v) for v in drained.host] == [0.0, 192.0, 384.0, 576.0]
+
+    calls.clear()
+    ex.submit({1: np.full((8, 8, 3), 5, np.float32),
+               3: np.full((8, 8, 3), 7, np.float32)})
+    assert len(calls) == 3                       # two frames + the mask
+    after = ex.obs.tracer.counters()
+    assert after["h2d_transfers"] - counters["h2d_transfers"] == 3
+    assert after["h2d_bytes"] - counters["h2d_bytes"] == 2 * ex.frame_bytes
+    drained = ex.drain()
+    assert drained.h2d_bytes == 2 * ex.frame_bytes
+    # clean slots keep the all-dirty tick's resident frames
+    assert [float(v) for v in drained.host] == [0.0, 960.0, 384.0, 1344.0]
+
+
+def test_all_dirty_and_partial_paths_give_bitwise_equal_outputs():
+    """The same frames through one all-dirty submit and through two
+    partial submits leave the same resident batch, so the rung step's
+    whole output tree is bitwise equal."""
+    frames = [s.image for s in _scenes(1, 4)[0]]
+    ex = _rung_executor(4, frames[0].shape, name="two_stage")
+    ex.submit(dict(enumerate(frames)))
+    whole = ex.drain().host
+    ex.reset()
+    ex.submit({0: frames[0], 2: frames[2]})
+    ex.drain()
+    ex.submit({1: frames[1], 3: frames[3]})
+    assert _trees_equal(ex.drain().host, whole)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_all_dirty_tick_owns_its_bytes_at_depth(depth):
+    """Distinct frames each tick, from host buffers the caller rewrites
+    right after every submit: the outputs at any depth equal a depth-1
+    run on fresh arrays, so no in-flight or aliased upload can see a
+    later tick's (or a poisoned) frame."""
+    n_ticks, cap = 4, 2
+    scenes = _scenes(n_ticks, cap, seed0=310)
+    shape = scenes[0][0].image.shape
+    ref = _rung_executor(cap, shape)
+    want = []
+    for t in range(n_ticks):
+        ref.submit({s: scenes[t][s].image.copy() for s in range(cap)})
+        want.append(ref.drain().host)
+    ex = _rung_executor(cap, shape, depth=depth)
+    bufs = [np.empty(shape, np.float32) for _ in range(cap)]
+    got = []
+    for t in range(n_ticks):
+        for s in range(cap):
+            np.copyto(bufs[s], scenes[t][s].image)
+        ex.submit(dict(enumerate(bufs)))
+        for buf in bufs:
+            buf.fill(np.nan)
+        if ex.ready():
+            got.append(ex.drain().host)
+    got += [d.host for d in ex.flush()]
+    assert len(got) == n_ticks
+    for a, b in zip(got, want):
+        assert _trees_equal(a, b)
+
+
+def test_staging_batch_waits_for_the_step_still_reading_it():
+    """A staging batch comes round again while the step over it has not
+    yet read it (the step reads its batch only after a slow loop): the
+    submit that rewrites it waits for that step, so each drained tick
+    sums its own frames.  On the CPU the put may alias the staging
+    batch, so rewriting it early would hand the running step a later
+    tick's frames."""
+    def step(raw):
+        m = jnp.full((256, 256), 1e-3, jnp.float32)
+        slow = jax.lax.fori_loop(0, 400, lambda i, a: jnp.tanh(a @ m), m)
+        return (raw + slow[0, 0] * 0.0).sum(axis=(1, 2, 3))
+
+    ex = PipelinedExecutor(step, 2, (4, 4, 3))
+    ex.warmup()
+    ticks = [{s: np.full((4, 4, 3), 10 * t + s, np.float32)
+              for s in range(2)} for t in range(ex.depth + 2)]
+    for frames in ticks:              # the last submit reuses the first batch
+        ex.submit(frames)
+    got = [[float(v) for v in d.host] for d in ex.flush()]
+    assert got == [[48.0 * (10 * t + s) for s in range(2)]
+                   for t in range(len(ticks))]
+
+
+_MESH_CHILD = r"""
+import json
+import numpy as np
+from repro.analysis import TraceSentinel
+from repro.batched import BatchedPerceptionEngine
+from repro.launch.mesh import make_local_mesh
+from repro.perception import SceneConfig, build_pipeline, generate_scene
+
+mesh = make_local_mesh(data=2)
+eng = BatchedPerceptionEngine(
+    build_pipeline("early_exit", scale=0.5, pad=False), capacity=4,
+    mesh=mesh)
+ex = eng.executor
+sids = [f"cam{i}" for i in range(4)]
+for sid in sids:
+    eng.join(sid)
+eng.compile()
+img = lambda t, i: generate_scene(SceneConfig("road", seed=i), t).image
+out = {}
+with TraceSentinel(compile_budget=0, transfer_guard="disallow") as sent:
+    _, outs = eng.tick({sid: img(0, i) for i, sid in enumerate(sids)})
+    raw = ex._raw
+    slot_of = {sid: eng.active[sid].slot for sid in sids}
+    out["all_dirty_served"] = sorted(outs)
+    out["sharding_equal"] = raw.sharding == ex._batch_sharding
+    out["shards"] = sorted(
+        [s.device.id, s.index[0].start, s.index[0].stop]
+        for s in raw.addressable_shards)
+    _, outs = eng.tick({"cam1": img(1, 1)})                # partial
+    out["partial_served"] = sorted(outs)
+    eng.leave("cam3")                                       # churn
+    _, outs = eng.tick({sid: img(2, i) for i, sid in enumerate(sids[:3])})
+    out["churn_served"] = sorted(outs)
+    eng.join("cam3")
+    _, outs = eng.tick({sid: img(3, i) for i, sid in enumerate(sids)})
+    out["rejoin_served"] = sorted(outs)
+out["compiles"] = sent.report().compiles
+host = np.asarray(raw)
+out["slot_values_ok"] = all(
+    np.array_equal(host[slot_of[sid]], img(0, i))
+    for i, sid in enumerate(sids))
+out["traces"] = [eng.trace_count, eng.assemble_trace_count,
+                 eng.update_trace_count]
+print(json.dumps(out))
+"""
+
+
+def test_all_dirty_upload_lands_sharded_on_a_two_device_mesh(tmp_path):
+    """On a data=2 mesh of forced host devices (a child process), the
+    all-dirty upload IS the resident batch with the slot-block sharding
+    (each device holds its own two slots), and a warmed engine serves
+    all-dirty, partial and churn ticks with no compile and no implicit
+    transfer."""
+    env = dict(os.environ)
+    flags = [f for f in env.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform_device_count")]
+    env["XLA_FLAGS"] = " ".join(
+        flags + ["--xla_force_host_platform_device_count=2"])
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(Path(__file__).resolve().parents[1] / "src"),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _MESH_CHILD], env=env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=tmp_path)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["sharding_equal"] is True
+    assert out["shards"] == [[0, 0, 2], [1, 2, 4]]
+    assert out["slot_values_ok"] is True
+    assert out["all_dirty_served"] == ["cam0", "cam1", "cam2", "cam3"]
+    assert out["partial_served"] == ["cam1"]
+    assert out["churn_served"] == ["cam0", "cam1", "cam2"]
+    assert out["rejoin_served"] == ["cam0", "cam1", "cam2", "cam3"]
+    assert out["compiles"] == 0
+    assert out["traces"] == [1, 1, 1]
 
 
 # --------------------------------- cost model: pipelined-latency mode -----
