@@ -6,10 +6,12 @@
 
 In one process, for each seed: build the cell, serve ``--seconds`` of its
 traffic exactly as ``bench/run.py`` does, and compare the run's seeded
-sample of served frames with the float32 reference (the lower readings).
-For the first ``--control-seeds`` seeds, the control as well: the
-reference computed wholly in bfloat16, put in the program's place on the
-same frames, compared with the float32 reference (the upper readings).
+sample of served frames with the configuration's reference in float32
+(the lower readings).  For the first ``--control-seeds`` seeds, the
+control as well: the same reference computed wholly in bfloat16
+(``Reference(config, key, jnp.bfloat16)``), put in the program's place
+on the same frames, compared with the float32 reference (the upper
+readings).
 One JSON line per seed and side.
 """
 from __future__ import annotations
@@ -39,7 +41,7 @@ def control_numbers(run, frames, lo, hi) -> dict:
         kind = run.config["rungs"][rung]["kind"]
         for a, b in zip(lo.detect(rung, images), hi.detect(rung, images)):
             rows.append((kind, a.boxes, a.n_proposals, b))
-    return compare(rows)
+    return compare(rows, float(run.config["frame"][1]))
 
 
 def main(argv=None) -> int:
@@ -55,10 +57,11 @@ def main(argv=None) -> int:
 
     import jax.numpy as jnp
 
-    from bench.reference import Reference, weights_key
+    from bench.detection import weights_key
 
     configure_compile_cache()
     cell = bench.load_cell(args.workload)
+    Reference = cell["reference"].Reference
     try:
         devs = bench.devices_for(cell["chips"])
     except bench.NoChip as e:

@@ -14,13 +14,13 @@ box the served run kept overlaps it by nearly the threshold.
   worst coordinate, between each served box and the nearest reference
   candidate, and between each reference kept box (not excused) and the
   nearest served box.  A box with nothing on the other side of its frame
-  counts ``MISSING_PX``.
+  counts ``missing_px``, the frame's width, which ``compare`` is handed.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from .reference import Detection, iou_one
+from .detection import Detection, iou_one
 
 # A dropped reference box is excused only where no served box lies within
 # this many pixels of it on every coordinate (so it has no counterpart)
@@ -29,25 +29,26 @@ from .reference import Detection, iou_one
 # moves an IoU between the small boxes of these frames.
 PAIR_PX = 2.0
 EXCUSE_IOU = 0.4
-# a box whose frame has nothing to compare it with: the frame's width
-MISSING_PX = 320.0
 
 
 def _pct(num: float, den: float):
     return 100.0 * num / den if den else None
 
 
-def _nearest(boxes: np.ndarray, others: np.ndarray) -> np.ndarray:
-    """Per box, the worst-coordinate gap to the nearest of ``others``."""
+def _nearest(boxes: np.ndarray, others: np.ndarray,
+             missing_px: float) -> np.ndarray:
+    """Per box, the worst-coordinate gap to the nearest of ``others``, or
+    ``missing_px`` where there are none."""
     if not len(others):
-        return np.full(len(boxes), MISSING_PX)
+        return np.full(len(boxes), missing_px)
     return np.abs(boxes[:, None] - others[None]).max(-1).min(1)
 
 
-def compare(frames: list) -> dict:
+def compare(frames: list, missing_px: float) -> dict:
     """``frames``: ``(kind, served_boxes, served_n_proposals, Detection)``
-    tuples.  Returns every number named in the module docstring (None
-    where nothing was there to count)."""
+    tuples; ``missing_px``: the frame's width in pixels.  Returns every
+    number named in the module docstring (None where nothing was there to
+    count)."""
     prop = [0, 0]
     kept_off = 0
     gaps: list[np.ndarray] = []
@@ -58,8 +59,8 @@ def compare(frames: list) -> dict:
             prop[0] += n_prop != ref.n_proposals
             prop[1] += 1
         kept_off += len(boxes) != len(ref.boxes)
-        gaps.append(_nearest(boxes, ref.candidates))
-        back = _nearest(ref.boxes, boxes)
+        gaps.append(_nearest(boxes, ref.candidates, missing_px))
+        back = _nearest(ref.boxes, boxes, missing_px)
         excused = np.array([
             g > PAIR_PX and len(boxes) > 0
             and iou_one(boxes, box).max() >= EXCUSE_IOU

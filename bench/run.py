@@ -7,18 +7,21 @@ Run from the root of a checkout on a TPU host.  The cell names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``) through ``BENCHMARK.json``; per-layer
 metrics are read by ``bench/metrics/<metric>.py`` and the limits that
-decide ``correct`` are in ``bench/limits/<cell>.json``.  Nothing here
-names a cell, a configuration, a mix or a metric.
+decide ``correct`` are in ``bench/limits/<cell>.json``.  The
+configuration names its model module (``"model"``: ``build_scheduler``,
+``flops_per_frame``, ``bytes_per_step``) and its plain reference
+(``"reference"``: ``Reference``) by path.  Nothing here names a cell, a
+configuration, a model, a mix or a metric.
 
 A run: places the compile cache as the program's entry points do, refuses
-any platform but a TPU, builds the scheduler (weights from one fixed key,
-``bench/reference.py``), compiles the rung engines the traffic uses,
-renders a scene pool per stream from ``--seed``, serves warm-up ticks,
-then serves ``--seconds`` of traffic under
-``TraceSentinel(compile_budget=0)``.  Once the window has closed it
-reads the device memory peak, frees the scheduler, re-runs a seeded
-sample of the served frames through the plain reference
-(``bench/reference.py``) and compares (``bench/compare.py``).  The last
+any platform but a TPU, builds the scheduler through the model module
+(weights from one fixed key, ``bench/detection.py``), compiles the rung
+engines the traffic uses, renders a scene pool per stream from
+``--seed`` at the configuration's frame, serves warm-up ticks, then
+serves ``--seconds`` of traffic under ``TraceSentinel(compile_budget=0)``.
+Once the window has closed it reads the device memory peak, frees the
+scheduler, re-runs a seeded sample of the served frames through the
+configuration's reference and compares (``bench/compare.py``).  The last
 line of standard output is one JSON object; the numbers compared, each
 with its limit, are also the last lines of standard error.
 """
@@ -67,9 +70,42 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def load_file(path: Path, mod_name: str):
+    """The Python file at ``path``, executed as a module of its own."""
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# what a configuration's ``model`` and ``reference`` modules must define
+PARTS = {"model": ("build_scheduler", "flops_per_frame", "bytes_per_step"),
+         "reference": ("Reference",)}
+
+
+def load_part(conf: str, cfg: dict, part: str, root: Path):
+    """The ``part`` module (``model`` or ``reference``) that configuration
+    ``conf`` names by its path under ``bench/``; anything missing is an
+    error that names the configuration."""
+    if part not in cfg:
+        raise ValueError(f"configuration {conf!r} names no {part!r} module")
+    path = (root / cfg[part]).resolve()
+    if (root / "bench").resolve() not in path.parents or not path.is_file():
+        raise ValueError(f"configuration {conf!r}: {part} module "
+                         f"{cfg[part]!r} is no file under bench/")
+    mod = load_file(path, f"bench_{part}_" + "".join(
+        c if c.isalnum() else "_" for c in conf))
+    missing = [n for n in PARTS[part] if not callable(getattr(mod, n, None))]
+    if missing:
+        raise ValueError(f"configuration {conf!r}: {part} module "
+                         f"{cfg[part]!r} defines no {', '.join(missing)}")
+    return mod
+
+
 def load_cell(name: str, root: Path | None = None) -> dict:
-    """The cell's entry in BENCHMARK.json with its configuration, traffic
-    mix, limits and metric entries resolved by name."""
+    """The cell's entry in BENCHMARK.json with its configuration, model and
+    reference modules, traffic mix, limits and metric entries resolved by
+    name."""
     root = ROOT if root is None else root
     spec = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
@@ -86,10 +122,13 @@ def load_cell(name: str, root: Path | None = None) -> dict:
     e2e = [m for m in spec["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
     names = {m["name"] for m in e2e}
+    cfg = load_json(root / conf["file"])
     return {
         "name": name,
         "chips": cell["chips"],
-        "config": load_json(root / conf["file"]),
+        "config": cfg,
+        "model": load_part(conf["name"], cfg, "model", root),
+        "reference": load_part(conf["name"], cfg, "reference", root),
         "traffic": load_json(root / "bench" / "traffic"
                              / f"{cell['traffic']}.json"),
         "limits": load_json(root / "bench" / "limits" / f"{name}.json"),
@@ -102,12 +141,8 @@ def load_reader(metric: str, root: Path | None = None):
     """``read(run)`` of ``bench/metrics/<metric>.py``."""
     root = ROOT if root is None else root
     path = root / "bench" / "metrics" / f"{metric}.py"
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in metric)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(path, "bench_metric_" + "".join(
+        c if c.isalnum() else "_" for c in metric)).read
 
 
 # ---------------------------------------------------------------- records
@@ -159,6 +194,11 @@ class Run:
     def config(self) -> dict:
         return self.cell["config"]
 
+    @property
+    def model(self):
+        """The configuration's model module."""
+        return self.cell["model"]
+
     def served(self) -> list:
         """Frames whose host post finished inside the window."""
         return [f for f in self.frames if f.done <= self.t_close]
@@ -202,23 +242,15 @@ def stream_budget(traffic: dict, i: int) -> float:
 
 
 def build(cell: dict, devs):
-    """The scheduler, its engines compiled for the rungs the traffic uses."""
-    from bench.reference import weights_key
-    from repro.batched.scheduler import RungBucketScheduler
+    """The scheduler the configuration's model module builds, its engines
+    compiled for the rungs the traffic uses."""
+    from bench.detection import weights_key
     from repro.launch.mesh import make_local_mesh
-    from repro.scenarios.replay import replay_ladder
 
     cfg, traffic = cell["config"], cell["traffic"]
-    ladder = replay_ladder(cfg["ladder"])
-    if [r.name for r in ladder] != cfg["ladder"]:
-        raise ValueError(f"the program orders the ladder "
-                         f"{[r.name for r in ladder]}, the configuration "
-                         f"{cfg['ladder']}")
     mesh = (make_local_mesh(data=cfg["mesh_data"], devices=devs)
             if cfg["mesh_data"] > 1 else None)
-    sched = RungBucketScheduler(ladder, capacity=cfg["capacity"],
-                                key=weights_key(), depth=cfg["depth"],
-                                mesh=mesh)
+    sched = cell["model"].build_scheduler(cfg, weights_key(), mesh)
     for name in rungs_used(traffic, cfg["ladder"]):
         sched.engines[name].compile()
     for i in range(cfg["streams"]):
@@ -232,7 +264,8 @@ def scene_pools(cell: dict, seed: int) -> tuple[list, list]:
     from repro.perception.data import Scene
 
     cfg, traffic = cell["config"], cell["traffic"]
-    pools = fleet_pools(seed, cfg["streams"], traffic["mix"], traffic["pool"])
+    pools = fleet_pools(seed, cfg["streams"], traffic["mix"], traffic["pool"],
+                        cfg["frame"])
     lanes = np.zeros((0, 2), np.float32)
     program = [[Scene(image=s.image, boxes=s.boxes, lane_pixels=lanes,
                       scenario=s.scenario, rain=s.rain) for s in pool]
@@ -418,8 +451,8 @@ def sample(run: Run, seed: int, n: int) -> list:
 
 
 def check(run: Run, frames: list, ref) -> dict:
-    """The numbers of ``compare`` for ``frames`` against ``ref``, a
-    ``bench.reference.Reference``."""
+    """The numbers of ``compare`` for ``frames`` against ``ref``, an
+    instance of the configuration's ``Reference``."""
     rows = []
     for rung in sorted({f.rung for f in frames}):
         fs = [f for f in frames if f.rung == rung]
@@ -427,7 +460,7 @@ def check(run: Run, frames: list, ref) -> dict:
         kind = run.config["rungs"][rung]["kind"]
         for f, det in zip(fs, ref.detect(rung, images)):
             rows.append((kind, f.out.boxes, f.out.num_proposals, det))
-    return compare(rows)
+    return compare(rows, float(run.config["frame"][1]))
 
 
 def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
@@ -482,7 +515,7 @@ def main(argv=None) -> int:
     peak = model_flops.peaks(devs[0].device_kind)
     t_init = time.perf_counter() - T_START
 
-    from bench.reference import Reference, weights_key
+    from bench.detection import weights_key
 
     if args.trace:
         def annotate(name):
@@ -531,7 +564,8 @@ def main(argv=None) -> int:
           f"{len(run.ticks)} ticks; {describe(run)}",
           file=sys.stderr)
     picked = sample(run, args.seed, cell["traffic"]["sample"])
-    numbers = check(run, picked, Reference(run.config, weights_key()))
+    numbers = check(run, picked,
+                    cell["reference"].Reference(run.config, weights_key()))
     ok, checks = verdict(numbers, cell["limits"])
     attempted = len(frames)
     failed = sum(f.out is None for f in frames)

@@ -1,6 +1,6 @@
-"""The benchmark's yardsticks on the CPU: operations and bytes, the peaks
-table, its copies of the scene generator and quality score, and the trace
-reduction."""
+"""The benchmark's yardsticks on the CPU: the det3x16 model module's
+operations and bytes, the peaks table, its copies of the scene generator
+and quality score, and the trace reduction."""
 import hashlib
 import json
 from pathlib import Path
@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from bench import model_flops
+from bench.models import det3x16
 from bench.quality import frame_quality
 from bench.scenes import fleet_pools, poisson_quantiles
 from bench.trace_reduce import reduce_profile, short_op, union
@@ -23,15 +24,15 @@ CAM8 = json.loads((ROOT / "bench/configs/det3x16-96x320-cam8.json").read_text())
     ("early_exit@0.5", 1_658_880),    # one conv, 24x80 output of 48x160
 ])
 def test_conv_flops_from_the_conv_formula(rung, expect):
-    assert model_flops.conv_flops(CAM8, rung) == expect
+    assert det3x16.conv_flops(CAM8, rung) == expect
 
 
 def test_flops_per_frame_adds_the_one_stage_head():
     # 2 * 12 * 40 cells * 16 channels * 4 outputs
-    assert (model_flops.flops_per_frame(CAM8, "one_stage")
-            - model_flops.conv_flops(CAM8, "one_stage")) == 61_440
-    assert (model_flops.flops_per_frame(CAM8, "two_stage")
-            == model_flops.conv_flops(CAM8, "two_stage"))
+    assert (det3x16.flops_per_frame(CAM8, "one_stage")
+            - det3x16.conv_flops(CAM8, "one_stage")) == 61_440
+    assert (det3x16.flops_per_frame(CAM8, "two_stage")
+            == det3x16.conv_flops(CAM8, "two_stage"))
 
 
 def test_peaks_table_and_unknown_kind():
@@ -44,17 +45,19 @@ def test_peaks_table_and_unknown_kind():
 
 def test_least_step_is_memory_bound_at_these_widths():
     peak = model_flops.peaks("TPU v5 lite")
-    t, bound = model_flops.least_step_seconds(CAM8, "two_stage", peak, 1)
+    t, bound = model_flops.least_step_seconds(det3x16, CAM8, "two_stage",
+                                              peak, 1)
     # 8 frames of 96x320x3 float32 read once already take 3.6 us
     assert bound == "memory"
     assert t >= 8 * 96 * 320 * 3 * 4 / 819e9
-    t4, _ = model_flops.least_step_seconds(CAM8, "two_stage", peak, 4)
+    t4, _ = model_flops.least_step_seconds(det3x16, CAM8, "two_stage",
+                                           peak, 4)
     assert t4 == pytest.approx(t / 4)
 
 
 def test_scene_pool_checksum_is_pinned():
     mix = [["city", 0.0], ["residential", 25.0], ["road", 120.0]]
-    pools = fleet_pools(2 ** 31 + 12345, 3, mix, 4)
+    pools = fleet_pools(2 ** 31 + 12345, 3, mix, 4, (96, 320, 3))
     h = hashlib.sha256()
     for pool in pools:
         for s in pool:
@@ -65,11 +68,44 @@ def test_scene_pool_checksum_is_pinned():
 
 
 def test_every_seed_draws_the_same_object_counts():
-    a = fleet_pools(1, 2, [["city", 0.0]], 16)
-    b = fleet_pools(2 ** 33 + 7, 2, [["city", 0.0]], 16)
+    a = fleet_pools(1, 2, [["city", 0.0]], 16, (96, 320, 3))
+    b = fleet_pools(2 ** 33 + 7, 2, [["city", 0.0]], 16, (96, 320, 3))
     counts = [sorted(len(s.boxes) for s in pool) for pool in a]
     assert counts == [sorted(len(s.boxes) for s in pool) for pool in b]
     assert counts[0] == sorted(poisson_quantiles(12.0, 16).tolist())
+
+
+KITTI = (375, 1242, 3)
+
+
+def test_scene_pool_renders_at_a_kitti_frame():
+    """A pool at KITTI's 375x1242x3: frames of that shape, every box inside
+    the frame and scaled from the base draws, the same object counts for
+    every seed, and the same pool again for the same seed."""
+    mix = [["city", 60.0], ["road", 0.0]]
+    a = fleet_pools(2 ** 31 + 7, 2, mix, 3, KITTI)
+    again = fleet_pools(2 ** 31 + 7, 2, mix, 3, KITTI)
+    b = fleet_pools(2 ** 33 + 1, 2, mix, 3, KITTI)
+    sy, sx = 375 / 96, 1242 / 320
+    for pool in a:
+        for s in pool:
+            assert s.image.shape == KITTI and s.image.dtype == np.float32
+            assert 0.0 <= s.image.min() and s.image.max() <= 1.0
+            y0, x0, y1, x1 = s.boxes.T
+            assert (y0 >= 375 // 3).all() and (x0 >= 0).all()
+            assert (y1 <= 375).all() and (x1 <= 1242).all()
+            assert ((y1 - y0 >= round(8 * sy))
+                    & (y1 - y0 <= round(27 * sy))).all()
+            assert ((x1 - x0 >= round(8 * sx))
+                    & (x1 - x0 <= round(35 * sx))).all()
+    assert ([[len(s.boxes) for s in pool] for pool in a]
+            == [[len(s.boxes) for s in pool] for pool in again])
+    assert ([sorted(len(s.boxes) for s in pool) for pool in a]
+            == [sorted(len(s.boxes) for s in pool) for pool in b])
+    for pa, pb in zip(a, again):
+        for sa, sb in zip(pa, pb):
+            assert sa.image.tobytes() == sb.image.tobytes()
+            assert sa.boxes.tobytes() == sb.boxes.tobytes()
 
 
 def test_scene_copy_draws_like_the_program_generator():
