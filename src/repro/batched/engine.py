@@ -54,6 +54,7 @@ from repro.perception.data import H, W
 from repro.perception.pipelines import (
     BuiltPipeline,
     FrameOutput,
+    SlotOutputs,
     build_pipeline,
     preprocess_device,
 )
@@ -72,6 +73,14 @@ def fused_batch_program(built: BuiltPipeline):
     ``step``."""
     return jax.vmap(
         lambda raw: built.infer(preprocess_device(raw, built.scale, built.pad)))
+
+
+def _count_post(per_slot: list, live) -> None:
+    """Add a post pass's counters (a ``SlotOutputs``'s) to the tracer of
+    ``live``, the observatory taking this tick's live spans, if any."""
+    if live is not None and isinstance(per_slot, SlotOutputs):
+        for name, value in per_slot.counters.items():
+            live.tracer.add(name, value)
 
 
 @dataclasses.dataclass
@@ -455,6 +464,7 @@ class BatchedPerceptionEngine:
             drained = self._exec.drain()
         with timer.stage("post_processing"):
             per_slot = self._post(drained.host, active_mask)
+            _count_post(per_slot, live)
             outputs: Dict[str, FrameOutput] = {
                 sid: per_slot[slot] for sid, slot in snapshot}
         rec = timer.finish()
@@ -502,6 +512,7 @@ class BatchedPerceptionEngine:
                 **self._span_tags(len(snapshot))):
             t0 = time.perf_counter()
             per_slot = self._post(drained.host, active_mask)
+            _count_post(per_slot, live)
             outputs: Dict[str, FrameOutput] = {
                 sid: per_slot[slot] for sid, slot in snapshot}
             post_s = time.perf_counter() - t0

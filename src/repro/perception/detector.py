@@ -8,7 +8,10 @@ variability axis (Insight 3), implemented so the *mechanism* is explicit:
 * two-stage: proposal head → host extracts a *variable-length* proposal
   list → per-proposal second stage + O(n²) host NMS.  Post-processing time
   scales with the proposal count — the paper's LaneNet/Faster-R-CNN
-  pathology, faithfully reproduced.
+  pathology, faithfully reproduced.  The batched engine's pass
+  (``post_host_batch``) runs one greedy NMS over every frame of a tick
+  (``batched_nms``), keeping per frame exactly what ``dynamic_nms`` keeps;
+  its work is still O(n²) in the proposal count.
 * early exit: the one-stage detector truncated after ``depth`` backbone
   convs (remaining stride recovered by average pooling) with a coarser
   objectness grid — the anytime ladder's cheapest rung: less compute,
@@ -31,6 +34,7 @@ __all__ = [
     "backbone_apply",
     "OneStageDetector",
     "TwoStageDetector",
+    "batched_nms",
     "dynamic_nms",
     "static_nms",
 ]
@@ -119,6 +123,91 @@ def dynamic_nms(boxes: np.ndarray, scores: np.ndarray, iou_thr: float = 0.5) -> 
         suppressed |= iou[i] > iou_thr
         suppressed[i] = True
     return np.asarray(keep, np.int64)
+
+
+def _x_overlap_pairs(x0: np.ndarray, x1: np.ndarray,
+                     frame: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Every same-frame pair ``(p, q)`` whose x extents may overlap: a
+    superset of the pairs with ``min(x1) - max(x0) > 0``.
+
+    Sweep and prune: candidates are sorted by (frame, ``x0``), and each
+    ``p`` pairs with the later ``q`` of its frame whose ``x0`` is at most
+    ``x1[p]``.  One stable sort of the ``x0`` and ``x1`` values together
+    gives those counts (an ``x0`` stays before an equal ``x1``); a NaN
+    sorts last and keeps its pairs.
+    """
+    n = len(x0)
+    o = np.lexsort((np.concatenate([x0, x1]), np.concatenate([frame, frame])))
+    x0_first = o < n
+    below = np.cumsum(x0_first) - x0_first     # x0s sorted before each entry
+    by_x = o[x0_first]                          # candidates in (frame, x0) order
+    x1_first = ~x0_first
+    end = np.empty(n, np.intp)
+    end[o[x1_first] - n] = below[x1_first]
+    n_after = np.maximum(end[by_x] - np.arange(1, n + 1), 0)
+    first = np.repeat(np.arange(n), n_after)
+    later = np.arange(len(first)) + np.repeat(
+        np.arange(1, n + 1) - np.cumsum(n_after) + n_after, n_after)
+    return by_x[first], by_x[later]
+
+
+def batched_nms(boxes: np.ndarray, scores: np.ndarray, counts: np.ndarray,
+                iou_thr: float = 0.5) -> tuple[np.ndarray, np.ndarray, int]:
+    """``dynamic_nms`` over several frames' candidates at once.
+
+    ``boxes`` (N, 4) and ``scores`` (N,) hold the frames' candidates one
+    frame after another, ``counts`` (B,) how many each frame has.  Returns
+    ``(keep, kept, sweeps)``: ``keep`` indexes ``boxes`` with every
+    frame's kept candidates, frame after frame, ``kept`` (B,) how many
+    each frame keeps, and ``sweeps`` the fixed-point passes taken (0
+    without candidates).  Frame ``b``'s run of ``keep``, less its first
+    index ``s``, is exactly ``dynamic_nms(boxes[s:e], scores[s:e],
+    iou_thr)``: the same indices in the same order.
+
+    Each frame is ranked by ``dynamic_nms``'s own ``argsort``, so ties
+    break alike.  IoU > ``iou_thr`` >= 0 needs overlapping x extents, so
+    only the pairs a sweep over ``x0`` finds get an IoU, computed with
+    ``_iou_matrix``'s float expressions, so the values are bit-equal.
+    Greedy keeps rank ``j`` iff no kept earlier rank ``i`` of its frame
+    has IoU > ``iou_thr`` with it; that is the unique fixed point of
+    ``keep = ~any(kept suppressor)``, which the sweeps reach from
+    all-kept after at most the longest suppression chain + 1 passes.
+    The work is still O(n²) in a frame's candidate count at worst.
+    """
+    if not iou_thr >= 0:
+        raise ValueError(f"iou_thr must be >= 0 (got {iou_thr})")
+    counts = np.asarray(counts, np.intp)
+    ends = np.cumsum(counts)
+    starts = ends - counts
+    n = int(ends[-1]) if len(ends) else 0
+    if n == 0:
+        return np.zeros(0, np.intp), np.zeros(len(counts), np.intp), 0
+    frame = np.repeat(np.arange(len(counts)), counts)
+    order = np.concatenate(     # rank order, frame by frame
+        [np.argsort(-scores[s:e]) for s, e in zip(starts, ends)]) + starts[frame]
+    # columns y0, x0, y1, x1 of the boxes in rank order
+    cols = np.ascontiguousarray(boxes.take(order, axis=0).T)
+    area = (np.maximum(cols[2] - cols[0], 0)
+            * np.maximum(cols[3] - cols[1], 0))
+    p, q = _x_overlap_pairs(cols[1], cols[3], frame)
+    i, j = np.minimum(p, q), np.maximum(p, q)   # earlier rank suppresses
+    ci, cj = cols.take(i, axis=1), cols.take(j, axis=1)
+    side = np.maximum(np.minimum(ci[2:], cj[2:])
+                      - np.maximum(ci[:2], cj[:2]), 0)
+    inter = side[0] * side[1]
+    union = area.take(i) + area.take(j) - inter
+    over = inter / np.maximum(union, 1e-9) > iou_thr
+    sup_by, sup = i[over], j[over]
+    keep = np.ones(n, bool)
+    sweeps = 0
+    while True:
+        sweeps += 1
+        nxt = np.ones(n, bool)
+        nxt[sup[keep[sup_by]]] = False
+        if not (nxt ^ keep).any():
+            break
+        keep = nxt
+    return order[keep], np.bincount(frame[keep], minlength=len(counts)), sweeps
 
 
 def static_nms(boxes: jax.Array, scores: jax.Array, k: int, iou_thr: float = 0.5):
@@ -315,12 +404,17 @@ class TwoStageDetector:
 
         Proposals from every active slot are gathered into a single
         (N, C) matrix, the per-proposal RoI refinement runs as N-row
-        matrix ops instead of a Python loop, and only the O(n²) NMS stays
-        per image.  Same math as the serial path (same dtypes, same
-        reduction axis), so outputs match ``post_host`` per slot.
+        matrix ops instead of a Python loop, and one ``batched_nms`` runs
+        the greedy NMS of every active slot at once; its work is still
+        O(n²) in each slot's proposal count.  Kept boxes are exactly
+        what ``dynamic_nms`` keeps per slot on the same refined boxes.
+        The refinement's N-row matrix products may round differently
+        from the serial path's per-proposal ones (BLAS sums in another
+        order), so boxes match ``post_host`` per slot to float32 rounding.
 
-        Returns a list of length B: ``(boxes, n_proposals)`` per active
-        slot, ``None`` for inactive ones.
+        Returns ``(per_slot, sweeps)``: a list of length B holding
+        ``(boxes, n_proposals)`` per active slot and ``None`` for inactive
+        ones, and the NMS's fixed-point passes.
         """
         B = obj.shape[0]
         if active is None:
@@ -340,14 +434,9 @@ class TwoStageDetector:
             [cy - bh / 2, cx - bw / 2, cy + bh / 2, cx + bw / 2], -1
         ).astype(np.float32)
         scores = (1.0 / (1.0 + np.exp(-out[:, 0]))).astype(np.float32)
-        results: list = []
-        for b in range(B):
-            if not active[b]:
-                results.append(None)
-                continue
-            m = bidx == b
-            bxs, n = boxes[m], int(m.sum())
-            if n:
-                bxs = bxs[dynamic_nms(bxs, scores[m])]
-            results.append((bxs, n))
-        return results
+        counts = np.bincount(bidx, minlength=B)     # bidx ascends
+        keep, kept, sweeps = batched_nms(boxes, scores, counts)
+        boxes = boxes.take(keep, axis=0)
+        ends = np.cumsum(kept)
+        return [(boxes[e - k:e], int(n)) if on else None
+                for on, n, k, e in zip(active, counts, kept, ends)], sweeps

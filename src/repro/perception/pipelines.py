@@ -32,6 +32,7 @@ from .lane import LaneDetector
 
 __all__ = [
     "FrameOutput",
+    "SlotOutputs",
     "BuiltPipeline",
     "PIPELINES",
     "register_pipeline",
@@ -122,6 +123,16 @@ class FrameOutput:
     num_proposals: float
 
 
+class SlotOutputs(list):
+    """A ``post_batch`` result: the per-slot ``FrameOutput`` list (``None``
+    for inactive slots) plus ``counters``, name → value totals of the
+    pass, for the batched engine to add to an attached tracer."""
+
+    def __init__(self, counters: dict) -> None:
+        super().__init__()
+        self.counters = counters
+
+
 @dataclasses.dataclass
 class BuiltPipeline:
     """A pipeline variant ready to run: a jitted device stage and a host
@@ -139,7 +150,9 @@ class BuiltPipeline:
     outputs plus an active-slot mask and returns a per-slot
     ``FrameOutput`` list (``None`` for inactive slots).  Factories that
     cannot vectorize their post stage leave it ``None``; the engine falls
-    back to slicing the batch through ``post`` per slot."""
+    back to slicing the batch through ``post`` per slot.  A ``post_batch``
+    may return a ``SlotOutputs``, whose ``counters`` the engine adds to an
+    attached tracer."""
 
     name: str
     scale: float
@@ -248,15 +261,18 @@ def _make_two_stage(scale: float = 1.0, key=None, pad: bool = True, **det_kw) ->
                            num_objects=float(len(boxes)),
                            num_proposals=float(n_prop))
 
-    def post_batch(host, active: np.ndarray) -> list:
+    def post_batch(host, active: np.ndarray) -> SlotOutputs:
         feat, obj = host
-        per_slot = det.post_host_batch(params, feat, obj, active=active)
-        outs: list[Optional[FrameOutput]] = []
+        per_slot, sweeps = det.post_host_batch(params, feat, obj,
+                                               active=active)
+        outs = SlotOutputs(counters={"nms_candidates": 0,
+                                     "nms_sweeps": sweeps})
         for slot in per_slot:
             if slot is None:
                 outs.append(None)
                 continue
             boxes, n_prop = slot
+            outs.counters["nms_candidates"] += n_prop
             outs.append(FrameOutput(
                 boxes=_unscale(boxes, scale, pad),
                 num_objects=float(len(boxes)), num_proposals=float(n_prop)))

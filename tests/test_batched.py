@@ -118,6 +118,81 @@ def test_batched_matches_serial_per_rung(name, scale, pad):
         assert np.allclose(out.boxes, ref.boxes, atol=1e-3)
 
 
+BENCH_MIXES = {
+    "city": [["city", 0.0]],
+    "rain": [["city", 60.0], ["residential", 25.0]],
+    "road": [["road", 0.0]],
+}
+
+
+@pytest.fixture(scope="module")
+def two_stage_ticks():
+    """Per mix, two ticks of 8 frames rendered by the benchmark's scene
+    generator, through the two-stage detector's device stage: (detector,
+    params, [(feat, obj)] per tick)."""
+    import jax
+
+    from bench.detection import weights_key
+    from bench.scenes import fleet_pools
+    from repro.perception import TwoStageDetector, preprocess_device
+
+    det = TwoStageDetector()
+    params = det.init(weights_key())
+    step = jax.jit(jax.vmap(
+        lambda raw: det.infer_device(params, preprocess_device(raw, 1.0, True))))
+    ticks = {}
+    for mix, streams in BENCH_MIXES.items():
+        pools = fleet_pools(4400000001, 8, streams, 2, (96, 320, 3))
+        ticks[mix] = [
+            jax.device_get(step(np.stack([pool[t].image for pool in pools])))
+            for t in range(2)]
+    return det, params, ticks
+
+
+def _per_frame_nms(boxes, scores, counts, iou_thr=0.5):
+    """The batched NMS's oracle: ``dynamic_nms`` on each frame alone."""
+    from repro.perception import dynamic_nms
+
+    starts = np.cumsum(counts) - counts
+    keeps = [dynamic_nms(boxes[s:s + n], scores[s:s + n], iou_thr) + s
+             for s, n in zip(starts, counts)]
+    return np.concatenate(keeps), np.array([len(k) for k in keeps]), 1
+
+
+@pytest.mark.parametrize("mix", sorted(BENCH_MIXES))
+@pytest.mark.parametrize("inactive", [(), (0, 3, 7)])
+def test_post_host_batch_is_the_per_frame_loop_on_bench_scenes(
+        two_stage_ticks, monkeypatch, mix, inactive):
+    """On the benchmark's scenes, the batched host post returns per slot
+    the very boxes (bit for bit) and proposal counts of the same pass
+    with ``dynamic_nms`` run frame by frame, and inactive slots stay
+    ``None``.  Against the serial ``post_host`` the proposal counts and
+    kept counts are equal and the boxes agree to float32 rounding of the
+    refinement's matrix products."""
+    from repro.perception import detector
+
+    det, params, ticks = two_stage_ticks
+    active = np.ones(8, bool)
+    active[list(inactive)] = False
+    for feat, obj in ticks[mix]:
+        got, sweeps = det.post_host_batch(params, feat, obj, active=active)
+        with monkeypatch.context() as m:
+            m.setattr(detector, "batched_nms", _per_frame_nms)
+            want, _ = det.post_host_batch(params, feat, obj, active=active)
+        assert sweeps >= 1
+        for b, (g, w) in enumerate(zip(got, want)):
+            if not active[b]:
+                assert g is None and w is None
+                continue
+            assert g[1] == w[1]
+            assert g[0].dtype == w[0].dtype and g[0].shape == w[0].shape
+            np.testing.assert_array_equal(g[0], w[0])
+            boxes, n = det.post_host(params, feat[b], obj[b])
+            assert g[1] == n
+            assert g[0].shape == boxes.shape
+            np.testing.assert_allclose(g[0], boxes, rtol=0, atol=1e-3)
+
+
 def test_no_retrace_on_join_and_leave():
     """Slot carve-out from the fixed-capacity padded batch: stream churn
     must never retrace the jitted batched step.  The sentinel counts

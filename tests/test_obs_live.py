@@ -266,6 +266,47 @@ def test_tracing_leaves_stage_records_alone(depth):
     assert bystander.tracer.counters() == {}
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_two_stage_tick_counts_its_nms_work(depth):
+    built = build_pipeline("two_stage", key=jax.random.PRNGKey(7),
+                           pad=False)
+    obs = Observatory()
+    eng = BatchedPerceptionEngine(built, capacity=3, depth=depth, obs=obs)
+    for i in range(2):                      # slot 2 stays inactive
+        eng.join(f"cam{i}")
+    proposals, ticks = 0, 0
+    for t in range(4):
+        before = obs.tracer.counters()
+        _, outs = eng.tick({f"cam{i}": generate_scene(
+            SceneConfig("city", seed=i), t).image for i in range(2)})
+        after = obs.tracer.counters()
+        if not outs:                        # the pipeline is filling
+            continue
+        ticks += 1
+        got = sum(o.num_proposals for o in outs.values())
+        assert got > 0
+        assert (after["nms_candidates"] - before.get("nms_candidates", 0)
+                == got)
+        assert after["nms_sweeps"] - before.get("nms_sweeps", 0) >= 1
+        proposals += got
+    assert ticks == 5 - depth
+    assert obs.tracer.counters()["nms_candidates"] == proposals
+
+
+@pytest.mark.parametrize("rung", ["one_stage", "early_exit"])
+def test_a_one_stage_tick_counts_no_nms_work(rung):
+    built = build_pipeline(rung, key=jax.random.PRNGKey(7))
+    obs = Observatory()
+    eng = BatchedPerceptionEngine(built, capacity=2, obs=obs)
+    eng.join("cam0")
+    _, outs = eng.tick({"cam0": generate_scene(SceneConfig("city", seed=0),
+                                                1).image})
+    assert outs["cam0"].num_objects > 0
+    counters = obs.tracer.counters()
+    assert counters["frames_submitted"] == 1
+    assert not {"nms_candidates", "nms_sweeps"} & set(counters)
+
+
 @pytest.mark.parametrize("name", sorted(GOLDEN_EPISODES))
 def test_golden_replays_stay_byte_identical_with_an_observatory(name):
     obs = Observatory()

@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.deadline import MeanDeadline, PercentileDeadline, WorstObserved, evaluate
 from repro.core.stats import Welford, coefficient_of_variation, latency_range, summarize
-from repro.perception.detector import dynamic_nms, static_nms
+from repro.perception.detector import batched_nms, dynamic_nms, static_nms
 from repro.models.attention import chunked_attention, dense_attention
 
 finite_latencies = st.lists(
@@ -96,6 +96,70 @@ def test_static_nms_agrees_with_dynamic_on_topk(n, seed):
     )
     keep_static = set(int(i) for i, m in zip(np.asarray(idx), np.asarray(keep_mask)) if m)
     assert keep_static == keep_dyn
+
+
+FRAME_KINDS = ("inactive", "single", "spread", "cluster", "staircase")
+
+
+def _frame_candidates(kind: str, rng) -> tuple[np.ndarray, np.ndarray]:
+    """One frame's (boxes, scores) of the given kind; ``inactive`` has
+    none, as a slot outside the tick's active mask."""
+    if kind == "inactive":
+        return np.zeros((0, 4), np.float32), np.zeros(0, np.float32)
+    if kind == "staircase":
+        # each box overlaps the next at IoU 2/3 and the one after at 3/7:
+        # one suppression chain through the whole frame
+        n = int(rng.integers(2, 40))
+        x = np.arange(n) * 2.0
+        boxes = np.stack([np.zeros(n), x, np.full(n, 10.0), x + 10], -1)
+        return boxes.astype(np.float32), np.linspace(1, 0.1, n).astype(np.float32)
+    n = 1 if kind == "single" else int(rng.integers(2, 40))
+    if kind == "cluster":                   # a few tight, near-equal clusters
+        centres = rng.uniform(0, 60, (int(rng.integers(1, 4)), 2))
+        c = centres[rng.integers(0, len(centres), n)] + rng.normal(0, 1.5, (n, 2))
+        hw = rng.uniform(8, 12, (n, 2))
+        boxes = np.concatenate([c - hw / 2, c + hw / 2], -1)
+    else:
+        y0, x0 = rng.uniform(0, 80, n), rng.uniform(0, 300, n)
+        boxes = np.stack([y0, x0, y0 + rng.uniform(4, 40, n),
+                          x0 + rng.uniform(4, 50, n)], -1)
+    scores = (rng.choice([0.6, 0.8, 1.0], n) if rng.random() < 0.5
+              else rng.uniform(0.1, 1.0, n))    # half the frames tie often
+    return boxes.astype(np.float32), scores.astype(np.float32)
+
+
+@given(st.lists(st.sampled_from(FRAME_KINDS), min_size=1, max_size=8),
+       st.sampled_from([0.0, 0.3, 0.5, 0.7]),
+       st.integers(min_value=0, max_value=2**32 - 1))
+@settings(max_examples=60, deadline=None)
+def test_batched_nms_is_dynamic_nms_per_frame(kinds, iou_thr, seed):
+    """One batched NMS over a tick keeps, in every frame, exactly the
+    indices ``dynamic_nms`` keeps on that frame alone, in the same order:
+    tied scores, empty and one-candidate frames, tight clusters and a
+    suppression chain as long as the frame."""
+    rng = np.random.default_rng(seed)
+    frames = [_frame_candidates(k, rng) for k in kinds]
+    boxes = np.concatenate([b for b, _ in frames])
+    scores = np.concatenate([s for _, s in frames])
+    counts = [len(s) for _, s in frames]
+    keep, kept, sweeps = batched_nms(boxes, scores, counts, iou_thr)
+    assert len(kept) == len(frames) and len(keep) == sum(kept)
+    runs = np.split(keep, np.cumsum(kept)[:-1])
+    for (b, s), run, start in zip(frames, runs, np.cumsum(counts) - counts):
+        want = dynamic_nms(b, s, iou_thr) if len(s) else np.zeros(0, np.int64)
+        assert run.dtype == want.dtype
+        np.testing.assert_array_equal(run - start, want)
+    assert sweeps == 0 if not len(scores) else sweeps >= 1
+    if iou_thr == 0.5:
+        # a chain of n boxes settles one link a pass: n passes in all
+        chains = [len(s) for k, (_, s) in zip(kinds, frames) if k == "staircase"]
+        assert sweeps >= max(chains, default=0)
+
+
+def test_batched_nms_refuses_a_negative_threshold():
+    with pytest.raises(ValueError, match="iou_thr"):
+        batched_nms(np.zeros((1, 4), np.float32), np.ones(1, np.float32),
+                    [1], -0.1)
 
 
 @given(st.integers(min_value=2, max_value=64), st.integers(min_value=0, max_value=10**6))
