@@ -10,8 +10,7 @@ over the declared input envelope and certifies, before any frame runs:
   aval signature;
 * **cost honesty** — static FLOP/byte counts yield a roofline latency
   floor per (rung, batch-size), cross-checked against the learned
-  cost-model priors (drift gate) and the measured benchmark p50s
-  (floor ≤ measurement, always);
+  cost-model priors (floor ≤ prior, and the drift gate);
 * **host hygiene** — no host-interaction primitive (callbacks, infeed,
   stray ``device_put``) hides inside a compiled program, and declared
   buffer donation matches what the traced program actually carries.

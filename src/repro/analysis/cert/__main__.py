@@ -9,7 +9,7 @@ compares against the committed ``analysis/certificate.json``; exit 1 on
 any fatal finding (retrace violation, signature/envelope drift, new host
 primitive, donation mismatch, roofline-vs-prior drift beyond ±25%).
 ``--regen`` rebuilds the static sections AND refreshes the measured
-priors/bench columns, then rewrites the certificate — review the diff
+priors, then rewrites the certificate — review the diff
 and commit it, golden-fixture style.  Exit codes: 0 clean, 1 findings,
 2 usage/environment error (e.g. no committed certificate).
 """
@@ -45,9 +45,6 @@ def main(argv=None) -> int:
                            "certificate")
     ap.add_argument("--cert", default=str(DEFAULT_CERT_PATH),
                     help="certificate path (default: %(default)s)")
-    ap.add_argument("--bench", default="BENCH_results.json",
-                    help="benchmark results for the measured p50 column "
-                         "(default: %(default)s)")
     ap.add_argument("--tol", type=float, default=DRIFT_TOL,
                     help="relative drift tolerance for the prior/floor "
                          "ratio gate (default: %(default)s)")
@@ -61,7 +58,7 @@ def main(argv=None) -> int:
 
     if args.regen:
         cert = build_static()
-        attach_measured(cert, bench_path=args.bench)
+        attach_measured(cert)
         problems = intrinsic_findings(cert)
         write_certificate(cert, cert_path)
         report = render_report(problems, [])

@@ -9,10 +9,10 @@ content, split by how they are produced:
   tracing (no XLA compile, no frame executes); any difference against
   the committed values is a finding.
 * **measured** — per-(rung, batch-size) cold-start cost-model priors
-  (``prior_s``, from a short calibration run) and the matching
-  ``BENCH_results.json`` tick p50s.  Only refreshed at ``--regen``,
-  committed like golden fixtures; ``--check`` treats them as constants
-  and re-derives just the *ratios* against the fresh floors.
+  (``prior_s``, from a short calibration run).  Only refreshed at
+  ``--regen``, committed like golden fixtures; ``--check`` treats them
+  as constants and re-derives just the *ratios* against the fresh
+  floors.
 
 Severity follows the retrace-hazard model: signature drift, sweep
 violations, new host primitives, and donation mismatches are **fatal**
@@ -55,8 +55,7 @@ DRIFT_TOL = 0.25
 def _cost_row(point, batch: int, env: InputEnvelope, hw: Hardware) -> dict:
     """Static roofline row for one (rung, batch-size): the vmapped fused
     step at batch ``batch`` — the exact program an engine with
-    ``capacity == batch`` runs, which is what ``benchmarks/batched.py``
-    measures as ``batched/{rung}/streams{batch}``."""
+    ``capacity == batch`` runs."""
     from repro.perception.pipelines import build_pipeline, preprocess_device
 
     built = build_pipeline(point.pipeline, scale=point.scale, pad=point.pad)
@@ -67,7 +66,7 @@ def _cost_row(point, batch: int, env: InputEnvelope, hw: Hardware) -> dict:
     counts = count_jaxpr(closed)
     in_b, out_b = program_io_bytes(closed)
     bytes_min = in_b + out_b
-    # BENCH steady state: every slot dirty every tick → h2d = whole batch
+    # steady state: every slot dirty every tick → h2d = whole batch
     h2d = float(batch * int(np.prod(env.image_shape)) * 4)
     floor = roofline_floor(counts.flops, bytes_min, h2d, hw)
     return {
@@ -80,7 +79,6 @@ def _cost_row(point, batch: int, env: InputEnvelope, hw: Hardware) -> dict:
         "floor_s": floor,
         "prior_s": None,
         "ratio": None,
-        "bench_p50_s": None,
     }
 
 
@@ -148,17 +146,13 @@ def build_static(env: InputEnvelope | None = None,
 
 
 def attach_measured(cert: dict, env: InputEnvelope | None = None,
-                    bench_path: str | Path | None = "BENCH_results.json",
                     calib_n: int = 4) -> dict:
     """Fill the measured columns at ``--regen`` time.
 
     * ``prior_s`` — the cold-start (rung, batch-size) cost-model prior
       from a short calibration (``anytime.calibrate`` at ``calib_n``
       frames per rung), via ``cold_start_prior_table``;
-    * ``ratio`` — ``prior_s / floor_s``, the drift-gate anchor;
-    * ``bench_p50_s`` — the measured batched tick p50 from
-      ``BENCH_results.json`` (``us_per_call`` there is per-frame: tick
-      wall / streams, so tick seconds = us_per_call × streams / 1e6).
+    * ``ratio`` — ``prior_s / floor_s``, the drift-gate anchor.
     """
     from repro.anytime.cost import cold_start_prior_table
     from repro.anytime.ladder import Rung, calibrate
@@ -170,29 +164,12 @@ def attach_measured(cert: dict, env: InputEnvelope | None = None,
     ladder = calibrate(rungs, SceneConfig(), n=calib_n)
     priors = cold_start_prior_table(list(ladder), env.batch_sizes)
 
-    bench: dict[tuple, float] = {}
-    if bench_path is not None and Path(bench_path).exists():
-        blob = json.loads(Path(bench_path).read_text())
-        records = [rec for mod in blob.get("benchmarks", {}).values()
-                   for rec in mod.get("results", [])]
-        for rec in records:
-            parts = rec.get("name", "").split("/")
-            if (len(parts) == 3 and parts[0] == "batched"
-                    and parts[2].startswith("streams")):
-                # us_per_call is per-frame (tick wall / streams): the
-                # whole-tick p50 the floor must undercut is × streams
-                streams = int(parts[2][len("streams"):])
-                bench[(parts[1], streams)] = (
-                    rec["us_per_call"] * streams / 1e6)
-
     for row in cert["cost_table"]:
         key = (row["rung"], row["batch_size"])
         if key in priors:
             row["prior_s"] = priors[key]
             row["ratio"] = (priors[key] / row["floor_s"]
                             if row["floor_s"] > 0 else None)
-        if key in bench:
-            row["bench_p50_s"] = bench[key]
     return cert
 
 
@@ -310,12 +287,6 @@ def check(committed: dict, fresh: dict, tol: float = DRIFT_TOL
                     f"(committed {ratio:.1f}, recomputed {live:.1f}, tol "
                     f"{tol:.0%}) — static cost and learned prior have "
                     "diverged; recalibrate or --regen")
-        bench = old.get("bench_p50_s")
-        if bench is not None and floor > bench:
-            fatal.append(
-                f"FLOOR {label}: static floor {floor * 1e3:.2f}ms exceeds "
-                f"the measured tick p50 {bench * 1e3:.2f}ms — the floor "
-                "is not a floor; fix the counts or the hardware model")
     return fatal, notes
 
 

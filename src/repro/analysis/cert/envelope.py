@@ -13,10 +13,9 @@ forces an explicit ``--regen``.
 
 Occupancies (1..capacity) drive the *certification* sweep: one engine
 per rung at fixed ``capacity``, join/leave/carve-out churn between
-ticks.  ``batch_sizes`` drive the *cost table*: they mirror the stream
-counts ``benchmarks/batched.py`` measures (an engine at capacity *b*,
-all slots dirty), so every cost row lines up with a measured
-``batched/{rung}/streams{b}`` p50 in ``BENCH_results.json``.
+ticks.  ``batch_sizes`` drive the *cost table*: one row per engine at
+capacity *b* with all slots dirty, each checked against the cost
+model's prior for that (rung, batch-size).
 """
 from __future__ import annotations
 
@@ -83,7 +82,7 @@ class InputEnvelope:
 
     capacity: int
     occupancies: tuple          # engine certification sweep (1..capacity)
-    batch_sizes: tuple          # cost-table batch sizes (BENCH stream counts)
+    batch_sizes: tuple          # cost-table batch sizes (stream counts)
     image_shape: tuple
     rungs: tuple                # RungPoint — batched engine rungs
     ladder_rungs: tuple         # RungPoint — anytime single-frame rungs
@@ -120,8 +119,8 @@ def envelope_hash(env: InputEnvelope) -> str:
 def default_envelope() -> InputEnvelope:
     """The shipped system's envelope.
 
-    * Batched rungs: the three rungs ``benchmarks/batched.py`` serves
-      (the ladder's top plus the cheap bounds), scale 1.0, padded input.
+    * Batched rungs: the three serving rungs (the ladder's top plus the
+      cheap bounds), scale 1.0, padded input.
     * Ladder rungs: ``anytime.default_rungs`` — the λ-scaled pad=False
       single-frame pipelines the contract controller can select.
     * Kernels: canonical shapes from ``repro.kernels.CERT_SHAPES``.

@@ -10,9 +10,9 @@ on hardware with those ceilings.  The certifier computes this floor per
 (rung, batch-size) from the static counts (``costs.py``) and uses it two
 ways:
 
-* **sanity** — the floor must sit at or below every *measured* p50 in
-  ``BENCH_results.json`` (a floor above a measurement means the counts
-  or the hardware model are wrong);
+* **sanity** — the floor must sit at or below the cost-model prior
+  committed for the same (rung, batch-size) (a floor above it means the
+  counts or the hardware model are wrong);
 * **drift gate** — the ratio ``prior / floor`` between the learned
   ``anytime/cost.py`` cold-start prior and the static floor is committed
   in the certificate.  ``--check`` recomputes the floor statically: if

@@ -656,7 +656,7 @@ class RungBucketScheduler:
     def report(self) -> list[dict]:
         """Per-stream outcome rows.  Floats go through ``json_num`` so an
         idle stream's undefined statistics serialize as ``null`` rather
-        than the non-strict ``NaN`` literal in ``BENCH_results.json``."""
+        than the non-strict ``NaN`` literal."""
         rows = []
         for sid, st in sorted(self.streams.items()):
             lats = np.asarray(st.latencies)

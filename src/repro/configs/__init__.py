@@ -1,14 +1,10 @@
 """Architecture configs (one module per assigned arch) + registry."""
 from .base import ModelConfig, reduced_for_smoke
-from .registry import ARCHS, SHAPES, InputShape, get_config, input_specs, shape_applicability
+from .registry import ARCHS, get_config
 
 __all__ = [
     "ModelConfig",
     "reduced_for_smoke",
     "ARCHS",
-    "SHAPES",
-    "InputShape",
     "get_config",
-    "input_specs",
-    "shape_applicability",
 ]

@@ -33,8 +33,8 @@ class ModelConfig:
     qkv_bias: bool = False           # Qwen2: bias on qkv projections
     rope_theta: float = 1e4
     sliding_window: Optional[int] = None   # architecture's own SWA (Mixtral)
-    # for full-attention archs, the window used *only* for the long_500k
-    # shape (sub-quadratic variant; see DESIGN.md §Arch-applicability)
+    # for full-attention archs, the KV window used *only* past a 32k
+    # context (sub-quadratic variant; see ``effective_window``)
     long_context_window: Optional[int] = 8192
 
     mlp_gated: bool = True           # SwiGLU (True) vs plain GELU MLP (False)
@@ -128,17 +128,6 @@ class ModelConfig:
     @property
     def supports_decode(self) -> bool:
         return not self.encoder_only
-
-    def supports_long_context(self) -> bool:
-        """Whether long_500k decode is sub-quadratic for this arch (natively
-        or via the sliding-window variant)."""
-        if self.encoder_only:
-            return False
-        if self.family in ("ssm", "hybrid"):
-            return True
-        if self.sliding_window is not None or self.long_context_window is not None:
-            return True
-        return False
 
     def effective_window(self, seq_len: int) -> Optional[int]:
         """KV window to use at a given context length: the arch's own SWA if

@@ -1,10 +1,10 @@
 """HuBERT X-Large [arXiv:2106.07447] — encoder-only audio transformer
 (w2v2 architecture).  Conv feature extractor is a STUB per the modality
-carve-out: ``input_specs`` provides 512-wide frame embeddings.
+carve-out: the model's ``batch["frames"]`` input is 512-wide frame
+embeddings.
 
 48 layers, d_model 1280, 16 heads (MHA), d_ff 5120, vocab 504 (k-means
-units for masked prediction).  Encoder-only ⇒ no decode shapes
-(DESIGN.md §4).
+units for masked prediction).  Encoder-only ⇒ no decode step.
 """
 from .base import ModelConfig
 

@@ -2,9 +2,9 @@
 modality carve-out) + Qwen2-0.5B language backbone.
 
 LM backbone: 24 layers, d_model 896, 14 heads (GQA kv=2), d_ff 4864,
-vocab 151655, QKV bias (Qwen2 convention).  The ViT is a stub:
-``input_specs`` provides precomputed patch embeddings (256 patches of
-width 1024 — InternViT-300M hidden size).
+vocab 151655, QKV bias (Qwen2 convention).  The ViT is a stub: the
+model's ``batch["patch_embeds"]`` input is precomputed patch embeddings
+(256 patches of width 1024 — InternViT-300M hidden size).
 """
 from .base import ModelConfig
 

@@ -1,21 +1,12 @@
-"""Architecture registry and input-shape catalogue.
-
-``get_config(arch_id)`` resolves ``--arch`` CLI flags; ``input_specs``
-builds the ShapeDtypeStruct stand-ins for every (architecture × input
-shape) pair consumed by the multi-pod dry-run (no device allocation).
-"""
+"""Architecture registry: ``get_config(arch_id)`` resolves ``--arch``
+CLI flags."""
 from __future__ import annotations
 
-import dataclasses
 import importlib
-from typing import Optional
-
-import jax
-import jax.numpy as jnp
 
 from .base import ModelConfig, reduced_for_smoke
 
-__all__ = ["ARCHS", "SHAPES", "get_config", "input_specs", "InputShape", "shape_applicability"]
+__all__ = ["ARCHS", "get_config"]
 
 # arch id → module name
 ARCHS: dict[str, str] = {
@@ -32,79 +23,9 @@ ARCHS: dict[str, str] = {
 }
 
 
-@dataclasses.dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES: dict[str, InputShape] = {
-    "train_4k": InputShape("train_4k", 4096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524288, 1, "decode"),
-}
-
-
 def get_config(arch: str, smoke: bool = False) -> ModelConfig:
     if arch not in ARCHS:
         raise KeyError(f"unknown arch {arch!r}; available: {sorted(ARCHS)}")
     mod = importlib.import_module(f"repro.configs.{ARCHS[arch]}")
     cfg = mod.CONFIG
     return reduced_for_smoke(cfg) if smoke else cfg
-
-
-def shape_applicability(cfg: ModelConfig, shape: InputShape) -> tuple[bool, str]:
-    """(runs?, reason).  Encodes DESIGN.md §Arch-applicability:
-    encoder-only archs have no decode step; long_500k needs sub-quadratic
-    attention (native SSM/hybrid/SWA, or the sliding-window variant)."""
-    if shape.kind == "decode":
-        if not cfg.supports_decode:
-            return False, "encoder-only: no decode step"
-        if shape.seq_len > 32768 and not cfg.supports_long_context():
-            return False, "quadratic full attention at 500k context"
-    return True, ""
-
-
-def input_specs(cfg: ModelConfig, shape: InputShape | str) -> dict:
-    """ShapeDtypeStruct stand-ins for one step of the given kind.
-
-    train/prefill → the ``batch`` argument of ``loss``/``forward``;
-    decode        → the ``tokens`` argument of ``decode_step`` (the decode
-                    *state* specs come from ``decode_state_specs``).
-    """
-    if isinstance(shape, str):
-        shape = SHAPES[shape]
-    b, s = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-
-    if shape.kind == "decode":
-        return {"tokens": jax.ShapeDtypeStruct((b,), i32)}
-
-    if cfg.family == "audio":
-        return {
-            "frames": jax.ShapeDtypeStruct((b, s, cfg.frontend_dim), jnp.float32),
-            "labels": jax.ShapeDtypeStruct((b, s), i32),
-        }
-    if cfg.family == "vlm":
-        p = cfg.frontend_tokens
-        return {
-            "tokens": jax.ShapeDtypeStruct((b, s - p), i32),
-            "patch_embeds": jax.ShapeDtypeStruct((b, p, cfg.frontend_dim), jnp.float32),
-        }
-    return {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
-
-
-def decode_state_specs(cfg: ModelConfig, shape: InputShape | str):
-    """ShapeDtypeStructs of the decode state (KV cache / SSM state) at this
-    shape's context length — via eval_shape, no allocation."""
-    from repro.models import Model
-
-    if isinstance(shape, str):
-        shape = SHAPES[shape]
-    model = Model(cfg)
-    return jax.eval_shape(
-        lambda: model.init_decode_state(shape.global_batch, shape.seq_len)
-    )

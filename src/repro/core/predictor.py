@@ -45,8 +45,8 @@ class Prediction:
 class GaussianPredictor:
     """Fits a stationary Gaussian to the stream ([51]: inference time is
     approximately Gaussian on mobile devices).  The paper notes this
-    performs poorly when variations are enormous — our benchmarks show
-    exactly that on the two-stage pipeline."""
+    performs poorly when variations are enormous, as on the two-stage
+    pipeline."""
 
     name = "gaussian"
 

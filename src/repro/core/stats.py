@@ -36,7 +36,7 @@ __all__ = [
 def json_num(x):
     """JSON-safe numeric: NaN/inf → None, else rounded to 9 places so
     serialized reports are stable and small.  Every report that may end
-    up in ``BENCH_results.json`` or a golden fixture must route its
+    up in a JSON file or a golden fixture must route its
     floats through here — ``json.dumps`` happily emits the non-strict
     ``NaN``/``Infinity`` literals that strict parsers reject."""
     if x is None:

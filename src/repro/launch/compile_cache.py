@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, placed from outside the program.
 
 Called by entry points (``chip_smoke.py``, ``launch/serve.py``,
-``benchmarks/run.py``), never at import: importing a module must not
+``bench/run.py``), never at import: importing a module must not
 change process-wide JAX configuration.
 """
 from __future__ import annotations

@@ -1,27 +1,14 @@
-"""Production meshes.
+"""Local device meshes for the fleet and the tests.
 
 Functions, not module-level constants: importing this module never touches
-jax device state (the dry-run must set XLA_FLAGS before first jax init).
+jax device state (a caller may set XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh_spec",
-           "PROD_SHAPE", "MULTIPOD_SHAPE"]
-
-PROD_SHAPE = (16, 16)            # 256 chips, one v5e pod
-MULTIPOD_SHAPE = (2, 16, 16)     # 2 pods × 256 chips
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """The target deployment mesh: (data=16, model=16) single pod, or
-    (pod=2, data=16, model=16) across two pods.  The ``pod`` axis composes
-    with ``data`` for batch sharding; see DESIGN.md §5."""
-    shape = MULTIPOD_SHAPE if multi_pod else PROD_SHAPE
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+__all__ = ["make_local_mesh", "parse_mesh_spec"]
 
 
 def make_local_mesh(data: int = 1, model: int = 1, devices=None):

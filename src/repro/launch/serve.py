@@ -166,10 +166,9 @@ def serve_fleet(args) -> None:
     streams, slot batches sharded over ``--mesh``'s data axis, ticked
     under deterministic virtual time (seeded ``ModeledStageCost``).
 
-    Doubles as the measurement child of ``benchmarks/fleet.py``: the
-    parent forces host device counts via XLA_FLAGS and reads the
-    ``--json-out`` report, so the scaling numbers come from real sharded
-    XLA programs even on a 1-accelerator CI host."""
+    ``--json-out`` writes a machine-readable report, so a parent that
+    forces host device counts via XLA_FLAGS can check real sharded XLA
+    programs even on a 1-accelerator CI host."""
     import json
     import time as _time
 
@@ -315,7 +314,7 @@ def main() -> None:
                     help="fleet mode: number of scheduler ticks to run")
     ap.add_argument("--json-out", default=None,
                     help="fleet mode: write the machine-readable run "
-                         "report (the benchmarks/fleet.py channel) here")
+                         "report here")
     ap.add_argument("--chaos", default=None, metavar="PLAN",
                     help="fleet mode: inject faults from PLAN — a FaultPlan "
                          "JSON file (repro.chaos) or a chaos-episode name "

@@ -13,8 +13,7 @@ Design constraints, in order:
 * **Low overhead** — recording a span is one clock read, a tuple
   construction, and a ring-buffer slot write under a lock.  The ring is
   preallocated (a fixed-length list), so the steady state allocates no
-  container storage and never triggers list growth; ``benchmarks/
-  obs_overhead.py`` holds the whole observatory to <3% frames/s.
+  container storage and never triggers list growth.
 * **Bounded memory** — the ring keeps the most recent ``capacity``
   spans; older spans are overwritten and counted in ``dropped`` (the CI
   smoke asserts zero drops at the default capacity).

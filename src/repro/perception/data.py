@@ -128,7 +128,7 @@ def varied_scene_stream(
     so conditions (scenario, rain, seed) may change frame to frame while
     the index keeps per-frame content evolving.  This is how a
     ``ScenarioTrace`` (``repro.scenarios``) renders a time-varying driving
-    episode through the same generator the stationary benchmarks use —
+    episode through the same generator the stationary streams use —
     e.g. ``varied_scene_stream(trace.stream_configs("cam_front"))``."""
     for cfg, i in configs:
         yield generate_scene(cfg, i)

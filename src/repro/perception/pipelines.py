@@ -10,8 +10,8 @@ drives any of them through the identical stage-timed loop, and the legacy
 (``repro.anytime``) addresses rungs by these registry names.
 
 Every run returns a ``TimelineRecorder`` whose records carry the stage
-breakdown plus metadata (``num_proposals``, ``num_objects``) so the
-benchmarks can compute the paper's correlations directly; ``collect=True``
+breakdown plus metadata (``num_proposals``, ``num_objects``) so a
+caller can compute the paper's correlations directly; ``collect=True``
 additionally returns per-frame detections in the original image frame so
 quality can be scored against ``Scene.boxes``.
 """

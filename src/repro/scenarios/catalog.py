@@ -3,8 +3,8 @@ related attack/tail-quality work) says a perception stack must survive.
 
 Every episode is a high-level ``Episode`` spec; ``compile_trace`` turns
 it into a replayable ``ScenarioTrace``.  Tick counts are deliberately
-small (an episode replays end-to-end in seconds on CPU) — benchmarks
-stretch them with ``tick_scale``.
+small (an episode replays end-to-end in seconds on CPU) — a longer
+replay stretches them with ``tick_scale``.
 
 | episode              | regime change exercised                           |
 |----------------------|---------------------------------------------------|

@@ -277,8 +277,8 @@ class ScenarioReplayer:
     depth, because byte-reproducible reports are defined on sync ticks —
     a modeled ``SimClock`` cannot observe real dispatch overlap, and the
     golden fixtures are contracts on the sync engine.  The requested
-    value is kept on ``.requested_depth`` so a wall-clock harness (e.g.
-    ``benchmarks.pipelined``) can drive the same trace pipelined.
+    value is kept on ``.requested_depth`` so a wall-clock harness can
+    drive the same trace pipelined.
     """
 
     def __init__(

@@ -371,7 +371,7 @@ def compile_trace(episode: Episode, seed: int, tick_scale: float = 1.0) -> Scena
     cumulative on the tick period, and every segment receives a derived
     sub-seed (mixed from ``seed`` and the segment index).  ``tick_scale``
     shrinks or stretches every phase's tick count (CI smoke replays at
-    half scale; benchmarks can stretch) without changing the segment
+    half scale; a longer replay can stretch) without changing the segment
     structure — the structure is a pure function of the spec, which is
     what the cross-seed property test relies on.
     """

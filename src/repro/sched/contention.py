@@ -1,7 +1,7 @@
 """Analytic cross-check for the multi-tenant contention curve.
 
-``benchmarks/multi_tenant.py`` measures CV / p99 versus co-resident
-streams on the real engine; this module builds the matching discrete-event
+``runtime.MultiTenantEngine`` serves co-resident streams on the real
+engine; this module builds the matching discrete-event
 scenario for ``sched.simulate`` (paper §III-E): N periodic inference tasks
 whose ``infer`` stages serialize on one non-preemptive accelerator while
 pre/post stages share the CPU cores.  The simulated curve shows the same

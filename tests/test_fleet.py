@@ -8,7 +8,7 @@ pure spec-mapping tests drive ``distributed.sharding`` with a stub mesh
 exposing only what those functions read (``.shape`` and
 ``.axis_names``); the one test that needs *actual* multi-device
 execution forces ``--xla_force_host_platform_device_count=2`` into a
-child process, exactly like ``benchmarks/fleet.py``.
+``repro.launch.serve --fleet`` child process.
 """
 from __future__ import annotations
 

@@ -648,20 +648,6 @@ def test_multi_tenant_engine_emits_obs_events():
     assert {"t0", "t1"} <= tenants
 
 
-# ---------------------------------------------------- train/data clock --
-
-def test_prefetch_iterator_accepts_injected_clock():
-    from repro.train.data import PrefetchIterator
-
-    clock = SimClock()
-    it = PrefetchIterator(iter([1, 2, 3]), depth=2, clock=clock.time)
-    assert list(it) == [1, 2, 3]
-    it._thread.join(timeout=5.0)
-    assert len(it.produce_times) == 3
-    # on a virtual clock that nobody advances, produce times are exactly 0
-    assert it.produce_times == [0.0, 0.0, 0.0]
-
-
 # ------------------------------------------------------- tvlint TV006 --
 
 def _tv006(src: str):

@@ -1,11 +1,10 @@
 """End-to-end behaviour tests: the paper's six insights must be observable
-in this framework's own pipelines, plus training/serving integration.
+in this framework's own pipelines, plus serving integration.
 """
 import math
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -118,36 +117,6 @@ def test_synchronizer_queue_overflow_drop_accounting():
     assert len(sync2.events) == 10
 
 
-# ------------------------------------------------- training integration ----
-def test_trainer_runs_and_loss_decreases():
-    from repro.launch.mesh import make_local_mesh
-    from repro.train import DataConfig, TrainConfig, Trainer, synthetic_batches
-    from repro.train.optimizer import AdamWConfig
-
-    cfg = get_config("qwen3-4b", smoke=True).replace(
-        num_layers=2, vocab_size=128, d_ff=128
-    )
-    model = Model(cfg)
-    mesh = make_local_mesh()
-    trainer = Trainer(
-        model, mesh,
-        TrainConfig(opt=AdamWConfig(lr=3e-3, warmup_steps=2, total_steps=30), log_every=1),
-    )
-    params, opt_state = trainer.init(jax.random.PRNGKey(0))
-    data = DataConfig(batch=4, seq_len=64)
-    losses = []
-    batches = (
-        {k: jnp.asarray(v) for k, v in b.items()}
-        for b in synthetic_batches(cfg, data)
-    )
-    params, opt_state = trainer.fit(
-        params, opt_state, batches, steps=8,
-        log=lambda i, m: losses.append(m["loss"]),
-    )
-    assert losses[-1] < losses[0]
-    assert trainer.recorder.records, "per-step latency must be recorded"
-
-
 def test_engine_generates_and_reports():
     from repro.runtime import Engine, ServeConfig
 
@@ -212,27 +181,3 @@ def test_init_params_deterministic_across_processes():
                              capture_output=True, text=True, check=True)
         sums.append(float(out.stdout.strip()))
     assert sums[0] == sums[1]
-
-
-def test_checkpoint_roundtrip(tmp_path):
-    from repro.train import latest_step, load_checkpoint, save_checkpoint
-    from repro.train.optimizer import adamw_init
-
-    cfg = get_config("yi-6b", smoke=True).replace(num_layers=2, vocab_size=64, d_ff=64)
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    opt = adamw_init(params)
-    save_checkpoint(str(tmp_path), 7, {"params": params, "opt": opt})
-    assert latest_step(str(tmp_path)) == 7
-    restored = load_checkpoint(str(tmp_path), {"params": params, "opt": opt})
-    for a, b in zip(jax.tree.leaves(restored["params"]), jax.tree.leaves(params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_checkpoint_shape_mismatch_rejected(tmp_path):
-    from repro.train import load_checkpoint, save_checkpoint
-
-    tree = {"w": jnp.zeros((4, 4))}
-    save_checkpoint(str(tmp_path), 1, tree)
-    with pytest.raises(ValueError):
-        load_checkpoint(str(tmp_path), {"w": jnp.zeros((4, 5))})
